@@ -527,6 +527,8 @@ TABLES = {
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     argv = sys.argv[1:]
     write_json = "--json" in argv
     which = [a for a in argv if not a.startswith("--")]

@@ -21,13 +21,13 @@ From the records two independent recounts are derived:
     buffer identity across the pass pair — matching the model's ``+ eps``
     convention.
   * ``recount_vmem_counts`` — the per-grid-step working set
-    ``(n_rhs_blocks, n_lhs_vecs, n_carry_rows, n_sweep_scratch)``,
-    classified from block shapes: lane-tiled blocks (minor dim ==
-    block_m, including lane-tiled VMEM scratch) are RHS-class blocks,
-    ``(rows, N-extent)`` blocks are the stacked shared LHS, small
-    ``(c, block_m)`` scratch rows are the streamed sweep carries, and
-    lane-tiled scratch spanning the FULL output N extent is the fused
-    kernels' resident intermediate (``SweepSpec.sweep_scratch``).  The
+    ``(n_blocks, n_lhs_rows, n_carry_rows, n_sweep_scratch)``, classified
+    from the specs: VMEM blocks of the in/out specs are the pipelined
+    (double-buffered) blocks, SMEM blocks are the stacked shared LHS
+    (their row count), small ``(c, block_m)`` scratch rows are the sweep
+    carries, and lane-tiled scratch spanning the FULL output N extent is
+    the full-N scratch (``SweepSpec.sweep_scratch``: the fused kernels'
+    intermediate, the resident batch kernel's factor coefficients).  The
     streamed pair reports the elementwise max over its two kernels (the
     forward's larger set — exactly what the budget check reasons with).
 
@@ -46,7 +46,7 @@ import math
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import block_shape_of, index_map_of
+from repro.kernels.common import block_shape_of, in_smem, index_map_of
 from repro.kernels.engine import (SweepSpec, batch_solver, recurrence_solver,
                                   shared_solver)
 
@@ -168,36 +168,31 @@ def recount_traffic_words(records: list) -> int:
 
 def recount_vmem_counts(records: list, *, block_m: int = TRACE_BLOCK_M
                         ) -> tuple:
-    """Independent ``(n_rhs_blocks, n_lhs_vecs, n_carry_rows,
+    """Independent ``(n_blocks, n_lhs_rows, n_carry_rows,
     n_sweep_scratch)`` recount — the elementwise max over the captured
     kernels' per-grid-step sets.
 
-    The fourth slot counts the FUSED kernels' full-N VMEM intermediates
-    (``SweepSpec.sweep_scratch``): lane-tiled scratch whose N extent
-    matches the full output sweep rather than a streamed chunk — zero for
-    every resident / two-call / recurrence kernel."""
+    The fourth slot counts full-N VMEM scratch: lane-tiled scratch whose
+    N extent matches the full output sweep (the fused kernels'
+    intermediates, the resident batch kernel's coefficients)."""
     counts = (0, 0, 0, 0)
     for rec in records:
         blocks = lhs = carry = sweep = 0
-        sweep_extents = set()
         n_extents = {tuple(o.shape)[0] for o in rec.out_shapes}
         for spec_ in tuple(rec.in_specs) + tuple(rec.out_specs):
             shape = block_shape_of(spec_)
             if _is_scalar_param(shape):
                 continue
-            if shape[-1] == block_m:
-                blocks += 1
-                sweep_extents.add(shape[0])
+            if in_smem(spec_):
+                lhs += shape[0]      # the stacked shared LHS rows
             else:
-                lhs += shape[0]
+                blocks += 1          # a pipelined VMEM block
         for scratch in rec.scratch_shapes:
             shape = tuple(scratch.shape)
-            if shape[0] in sweep_extents:
-                blocks += 1          # lane-tiled full-sweep scratch
-            elif shape[-1] == block_m and shape[0] in n_extents:
-                sweep += 1           # fused full-N intermediate scratch
+            if shape[-1] == block_m and shape[0] in n_extents:
+                sweep += 1           # full-N scratch
             else:
-                carry += shape[0]    # streamed carry rows
+                carry += shape[0]    # carry rows
         counts = tuple(max(a, b)
                        for a, b in zip(counts, (blocks, lhs, carry, sweep)))
     return counts

@@ -285,20 +285,13 @@ def _check_accounting(spec, out: list) -> None:
             f"code"))
     got_vmem = recount_vmem_counts(records)
     want_vmem = tuple(spec.vmem_counts()) + (spec.sweep_scratch(),)
-    # resident kernels carry sweep state in registers, not scratch — only
-    # the first two classes are observable (and used by check_vmem);
-    # streamed pairs add the carry rows, fused kernels the full-N scratch
-    fused = getattr(spec, "fused", False)
-    compare = 4 if fused else (3 if spec.streamed else 2)
-    labels = ("blocks", "lhs_vecs", "carry_rows", "sweep_scratch")
-    if got_vmem[:compare] != want_vmem[:compare]:
+    labels = ("blocks", "lhs_rows", "carry_rows", "sweep_scratch")
+    if got_vmem != want_vmem:
         out.append(Finding(
             "speccheck", spec.name,
-            f"VMEM residency drift: builders hold {got_vmem[:compare]} "
-            f"({', '.join(labels[:compare])}) "
-            f"but SweepSpec.vmem_counts claims "
-            f"{want_vmem[:compare]} — the budget check no longer "
-            f"matches the code"))
+            f"VMEM residency drift: builders hold {got_vmem} "
+            f"({', '.join(labels)}) but SweepSpec.vmem_counts claims "
+            f"{want_vmem} — the budget check no longer matches the code"))
 
 
 def _check_storage_pricing(spec, out: list) -> None:

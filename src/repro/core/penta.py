@@ -184,16 +184,31 @@ def periodic_penta_factor(a, b, c, d, e) -> PeriodicPentaFactor:
     M4 = jnp.eye(4, dtype=c.dtype) + _vty(vcoef, Z)  # (4, 4)
     # the adjoint's auxiliary solves A'^{-T} V, also once per operator
     Zt = penta_solve_t(f, _corner_V(vcoef, n))       # (N, 4)
-    return PeriodicPentaFactor(factor=f, Z=Z, Minv=jnp.linalg.inv(M4),
-                               vcoef=vcoef, Zt=Zt)
+    # the TPU's default matmul precision is one bf16 pass
+    with jax.default_matmul_precision("highest"):
+        minv = jnp.linalg.inv(M4)
+    return PeriodicPentaFactor(factor=f, Z=Z, Minv=minv, vcoef=vcoef, Zt=Zt)
+
+
+#: The corner corrections' small matmuls run at full fp32 precision: at
+#: the TPU's default (one bf16 pass) they would cost ~3 significant digits.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def periodic_corner_correction(pf: PeriodicPentaFactor,
+                               y: jax.Array) -> jax.Array:
+    """Rank-4 Woodbury corner step on y = A'^{-1} rhs:
+    x = y - Z (I + V^T Z)^{-1} V^T y.  Shared by the reference solve below
+    and the ``pallas`` backend's kernel-produced y."""
+    w = jnp.matmul(pf.Minv, _vty(pf.vcoef, y), precision=_HIGHEST)
+    return y - jnp.tensordot(pf.Z, w, axes=([1], [0]), precision=_HIGHEST)
 
 
 def periodic_penta_solve(pf: PeriodicPentaFactor, rhs: jax.Array, *,
                          method: str = "scan", unroll: int = 1) -> jax.Array:
     """x = y - Z (I + V^T Z)^{-1} V^T y  with  y = A'^{-1} rhs."""
     y = penta_solve(pf.factor, rhs, method=method, unroll=unroll)
-    w = pf.Minv @ _vty(pf.vcoef, y)            # (4,) or (4, M)
-    return y - jnp.tensordot(pf.Z, w, axes=([1], [0]))
+    return periodic_corner_correction(pf, y)
 
 
 def _corner_V(vcoef: jax.Array, n: int) -> jax.Array:
@@ -219,8 +234,8 @@ def periodic_corner_correction_t(pf: PeriodicPentaFactor,
     kernel-produced y — ONE home for the corner algebra.
     """
     uty = jnp.stack([y[0], y[1], y[-2], y[-1]], axis=0)            # U^T y
-    h = pf.Minv.T @ uty
-    return y - jnp.tensordot(pf.Zt, h, axes=([1], [0]))
+    h = jnp.matmul(pf.Minv.T, uty, precision=_HIGHEST)
+    return y - jnp.tensordot(pf.Zt, h, axes=([1], [0]), precision=_HIGHEST)
 
 
 def periodic_penta_solve_t(pf: PeriodicPentaFactor, g: jax.Array, *,

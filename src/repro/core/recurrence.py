@@ -94,19 +94,17 @@ def _auto_blocks(order: int, n: int, m: int, itemsize: int) -> tuple:
     ``RecurrenceSpec``."""
     from repro.kernels import common as kcommon
     from repro.kernels.engine import find_recurrence_spec
-    n_rhs, n_lhs, n_carry = find_recurrence_spec(order).vmem_counts()
+    resident = find_recurrence_spec(order)
+    streamed = find_recurrence_spec(order, streamed=True)
     cap = max(kcommon.LANE, m)
     for bm in _BLOCK_M_CANDIDATES:
         if bm > max(cap, _BLOCK_M_CANDIDATES[-1]):
             continue
-        ws = kcommon.vmem_working_set(n, bm, n_rhs, n_lhs, itemsize=itemsize)
-        if ws <= kcommon.VMEM_BUDGET_BYTES:
+        if kcommon.spec_fits(resident, n, bm, itemsize=itemsize):
             return bm, None
     bm = _BLOCK_M_CANDIDATES[-1]
     for bn in _BLOCK_N_CANDIDATES:
-        ws = kcommon.streamed_vmem_working_set(bn, bm, n_rhs, n_lhs, n_carry,
-                                               itemsize=itemsize)
-        if ws <= kcommon.VMEM_BUDGET_BYTES:
+        if kcommon.spec_fits(streamed, n, bm, bn, itemsize=itemsize):
             return bm, bn
     return bm, _BLOCK_N_CANDIDATES[-1]
 
@@ -272,7 +270,9 @@ def linear_recurrence(
             h_new = p_i * h + q_i
             return h_new, h_new
 
-        init = jnp.zeros(q.shape[1:], dtype) if h0 is None else h0
+        # built from the operand so that, inside shard_map, the carry is
+        # typed as varying over the same mesh axes as q
+        init = jnp.zeros_like(q[0]) if h0 is None else h0
         _, h = jax.lax.scan(step, init, (p, q), reverse=reverse, unroll=unroll)
         return h
 
@@ -340,7 +340,8 @@ def linear_recurrence2(
             h_new = s_i * h1 + t_i * h2 + u_i
             return (h_new, h1), h_new
 
-        zeros = jnp.zeros(u.shape[1:], dtype)
+        # from the operand: varying over u's mesh axes inside shard_map
+        zeros = jnp.zeros_like(u[0])
         init = (zeros, zeros) if h0 is None else h0
         _, h = jax.lax.scan(step, init, (s, t, u), reverse=reverse,
                             unroll=unroll)

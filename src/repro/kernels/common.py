@@ -12,9 +12,18 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# VMEM is ~16 MiB/core on recent TPUs; leave headroom for double buffering.
-VMEM_BUDGET_BYTES = 12 * 1024 * 1024  # conservative per-kernel working set
+# Budgets of the fit checks, in bytes.  Pallas double-buffers every
+# blocked operand (inputs AND outputs), so the checks count each pipelined
+# block twice and scratch once (``vmem_working_set``).  The VMEM budget is
+# the scoped limit Mosaic applies by default on a TPU v5e; the compiled
+# kernels request VMEM_LIMIT_BYTES, which leaves the difference for the
+# compiler's own internal scratch.  SMEM holds the shared factored LHS as
+# a scalar table (1 MiB per core on v5e).
+VMEM_BUDGET_BYTES = 16 * 1024 * 1024
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+SMEM_BUDGET_BYTES = 1024 * 1024
 LANE = 128          # TPU lane width — one system per lane (paper: one per thread)
 SUBLANE = 8         # VREG sublane depth — sweep unroll granularity
 
@@ -29,9 +38,10 @@ def canonical_storage_dtype(storage_dtype):
 
     ``None`` means "store at the operand dtype" (no mixed precision) and
     passes through.  The short alias ``"bf16"`` (and ``"bfloat16"``) maps
-    to ``jnp.bfloat16`` — the storage precision of the mixed path: stored
-    factor / diagonals / RHS live at this dtype in HBM, all carries and
-    accumulation stay at least fp32 in-kernel.  Non-floating dtypes are
+    to ``jnp.bfloat16`` — the storage precision of the mixed path: the
+    (N, M) streams (per-lane diagonals, RHS) live at this dtype in HBM;
+    the shared factor (32-bit SMEM scalars), all carries and accumulation
+    stay at least fp32 in-kernel.  Non-floating dtypes are
     rejected (integer storage would silently quantise the factor)."""
     if storage_dtype is None:
         return None
@@ -45,18 +55,38 @@ def canonical_storage_dtype(storage_dtype):
     return dt
 
 
-def row(ref, i, width):
-    """Load row i (dynamic) of a 2-D ref -> (width,) vector."""
-    return ref[pl.ds(i, 1), :].reshape((width,))
+def row(ref, i):
+    """Load row i (dynamic) of a 2-D VMEM ref as a (1, width) vector.
+
+    Rows stay 2-D: a (1, BLOCK_M) value occupies one sublane of each lane
+    tile, which is the layout Mosaic loads and stores at a dynamic sublane
+    offset.  A 1-D (BLOCK_M,) value would need a relayout per step."""
+    return ref[pl.ds(i, 1), :]
 
 
 def store_row(ref, i, val):
-    ref[pl.ds(i, 1), :] = val.reshape((1,) + val.shape)
+    """Store a (1, width) row at row i (dynamic) of a 2-D VMEM ref."""
+    ref[pl.ds(i, 1), :] = val
 
 
-def scalar(ref, r, i):
-    """Load element [r, i] (r static, i dynamic) of a 2-D ref -> scalar."""
-    return ref[r:r + 1, pl.ds(i, 1)].reshape(())
+def widen_rows(src_ref, dst_ref, off=0) -> None:
+    """Copy a packed storage-dtype block (bf16) into rows ``off:`` of a
+    compute-dtype ref, converting on the way.
+
+    A single bf16 row cannot be loaded at a dynamic sublane offset (bf16
+    packs 16 rows per (8, 128) tile), so the sweeps never read the packed
+    block row by row: the block is widened once, 16 rows per step, and
+    the sweep then runs in place on the fp32 copy."""
+    rows = src_ref.shape[0]
+    step = 16 if rows % 16 == 0 else rows
+
+    def body(t, carry):
+        r = pl.multiple_of(t * step, step)
+        dst_ref[pl.ds(off + r, step), :] = \
+            src_ref[pl.ds(r, step), :].astype(dst_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, rows // step, body, 0)
 
 
 def pad_to_multiple(x: jax.Array, multiple: int, axis: int, *,
@@ -115,43 +145,76 @@ def pad_sweep(x: jax.Array, block_n: int, axis: int = 0, *,
     return pad_to_multiple(x, block_n, axis, value=1.0 if identity else 0.0)
 
 
-def vmem_working_set(n: int, block_m: int, n_rhs_blocks: int, n_lhs_vecs: int,
-                     itemsize: int = 4) -> int:
-    """Bytes of VMEM a solver grid step holds: RHS/out blocks + shared LHS."""
-    return (n_rhs_blocks * n * block_m + n_lhs_vecs * n) * itemsize
+def vmem_working_set(n: int, block_m: int, n_blocks: int, *,
+                     block_n: int | None = None, n_scratch: int = 0,
+                     n_carry: int = 0, itemsize: int = 4) -> int:
+    """Bytes of VMEM one grid step allocates.
 
-
-def streamed_vmem_working_set(block_n: int, block_m: int, n_rhs_blocks: int,
-                              n_lhs_vecs: int, n_carry: int,
-                              itemsize: int = 4) -> int:
-    """Bytes of VMEM a *streamed* (split-N) grid step holds: the N-chunked
-    RHS/out blocks + the N-chunked shared LHS + the carry rows that thread
-    the sweep state across sequential N-chunks."""
-    return (n_rhs_blocks * block_n * block_m + n_lhs_vecs * block_n
+    Each of the ``n_blocks`` lane-tiled operand blocks — ``(n, block_m)``
+    resident, ``(block_n, block_m)`` streamed — is counted twice, because
+    Pallas double-buffers every blocked input and output.  Scratch is
+    allocated once: ``n_scratch`` full-N ``(n, block_m)`` arrays (the fused
+    kernels' intermediate, the batch factor coefficients) and ``n_carry``
+    carry rows.  Everything is priced at ``itemsize`` — callers pass the
+    compute itemsize, which bounds the packed storage itemsize from above."""
+    rows = n if block_n is None else block_n
+    return (2 * n_blocks * rows * block_m + n_scratch * n * block_m
             + n_carry * block_m) * itemsize
 
 
-def check_vmem(n: int, block_m: int, n_rhs_blocks: int, n_lhs_vecs: int,
+def smem_working_set(lhs_rows: int, extent: int, itemsize: int = 4) -> int:
+    """Bytes of SMEM the shared factored LHS block takes: ``lhs_rows`` rows
+    of ``extent`` scalars (N resident, BLOCK_N streamed).  Counted as two
+    buffers with the rows padded to the 8-row tile — an upper bound on
+    what Mosaic allocates."""
+    if not lhs_rows:
+        return 0
+    rows = -(-lhs_rows // SUBLANE) * SUBLANE
+    return 2 * rows * extent * itemsize
+
+
+def spec_working_set(spec, n: int, block_m: int, block_n: int | None = None,
+                     itemsize: int = 4) -> tuple:
+    """``(vmem_bytes, smem_bytes)`` one grid step of ``spec``'s kernels
+    allocates, from the spec's own counts (``vmem_counts`` /
+    ``sweep_scratch``) — the single fit model of the ops-layer checks and
+    of every tuner."""
+    n_blocks, n_lhs, n_carry = spec.vmem_counts()
+    vmem = vmem_working_set(n, block_m, n_blocks, block_n=block_n,
+                            n_scratch=spec.sweep_scratch(), n_carry=n_carry,
+                            itemsize=itemsize)
+    smem = smem_working_set(n_lhs, n if block_n is None else block_n,
+                            itemsize=itemsize)
+    return vmem, smem
+
+
+def spec_fits(spec, n: int, block_m: int, block_n: int | None = None,
+              itemsize: int = 4) -> bool:
+    vmem, smem = spec_working_set(spec, n, block_m, block_n, itemsize)
+    return vmem <= VMEM_BUDGET_BYTES and smem <= SMEM_BUDGET_BYTES
+
+
+def check_fits(spec, n: int, block_m: int, block_n: int | None = None,
                itemsize: int = 4) -> None:
-    ws = vmem_working_set(n, block_m, n_rhs_blocks, n_lhs_vecs,
-                          itemsize=itemsize)
-    if ws > VMEM_BUDGET_BYTES:
+    """Raise ``ValueError`` when ``spec`` at these blocks busts the VMEM or
+    SMEM budget (the message names the knob to turn)."""
+    vmem, smem = spec_working_set(spec, n, block_m, block_n, itemsize)
+    where = (f"N={n}, BLOCK_M={block_m}" if block_n is None
+             else f"N={n}, BLOCK_N={block_n}, BLOCK_M={block_m}")
+    if vmem > VMEM_BUDGET_BYTES:
+        hint = ("reduce block_m or split N (HBM-streamed variant)"
+                if block_n is None else
+                "the full-N scratch does not fit — spill to the two-call "
+                "streamed pair (fused=False) or reduce block_m"
+                if spec.fused else "reduce block_n or block_m")
         raise ValueError(
-            f"solver working set {ws/2**20:.1f} MiB exceeds VMEM budget "
-            f"({VMEM_BUDGET_BYTES/2**20:.0f} MiB): N={n}, BLOCK_M={block_m}. "
-            f"Reduce block_m or split N (HBM-streamed variant).")
-
-
-def check_vmem_streamed(block_n: int, block_m: int, n_rhs_blocks: int,
-                        n_lhs_vecs: int, n_carry: int,
-                        itemsize: int = 4) -> None:
-    ws = streamed_vmem_working_set(block_n, block_m, n_rhs_blocks, n_lhs_vecs,
-                                   n_carry, itemsize=itemsize)
-    if ws > VMEM_BUDGET_BYTES:
+            f"{spec.name} working set {vmem/2**20:.1f} MiB exceeds VMEM "
+            f"budget ({VMEM_BUDGET_BYTES/2**20:.0f} MiB): {where}. {hint}.")
+    if smem > SMEM_BUDGET_BYTES:
         raise ValueError(
-            f"streamed solver working set {ws/2**20:.1f} MiB exceeds VMEM "
-            f"budget ({VMEM_BUDGET_BYTES/2**20:.0f} MiB): BLOCK_N={block_n}, "
-            f"BLOCK_M={block_m}. Reduce block_n or block_m.")
+            f"{spec.name} shared LHS block {smem/2**10:.0f} KiB exceeds SMEM "
+            f"budget ({SMEM_BUDGET_BYTES/2**10:.0f} KiB): {where}. Split N "
+            f"(HBM-streamed variant).")
 
 
 # -- streamed (split-N) grid plumbing ---------------------------------------
@@ -181,15 +244,32 @@ def chunk_spec(block_n: int, block_m: int, num_n: int, *,
     return pl.BlockSpec((block_n, block_m), lambda j, k: (k, j))
 
 
+def lhs_spec(rows: int, n: int):
+    """BlockSpec for the whole stacked (rows, N) shared LHS, resident in
+    SMEM across the lane-tile grid.  The sweep reads one coefficient per
+    row and broadcasts it across the lane tile: a scalar read at a dynamic
+    index is what SMEM serves (in VMEM it would be a width-1 slice at a
+    dynamic lane offset, which Mosaic refuses)."""
+    return pl.BlockSpec((rows, n), lambda j: (0, 0),
+                        memory_space=pltpu.SMEM)
+
+
+def param_spec():
+    """BlockSpec for a (1, 1) scalar parameter (the uniform eps) in SMEM."""
+    return pl.BlockSpec((1, 1), lambda *_: (0, 0), memory_space=pltpu.SMEM)
+
+
 def chunk_lhs_spec(rows: int, block_n: int, num_n: int, *,
                    reverse: bool = False):
-    """BlockSpec for a stacked (rows, N) shared LHS chunked along N.  Every
-    lane tile re-walks the same chunks — the single stored LHS copy of the
-    paper, streamed through VMEM instead of resident."""
+    """BlockSpec for a stacked (rows, N) shared LHS chunked along N, in
+    SMEM.  Every lane tile re-walks the same chunks — the single stored
+    LHS copy of the paper, streamed instead of resident."""
     if reverse:
         return pl.BlockSpec((rows, block_n),
-                            lambda j, k: (0, num_n - 1 - k))
-    return pl.BlockSpec((rows, block_n), lambda j, k: (0, k))
+                            lambda j, k: (0, num_n - 1 - k),
+                            memory_space=pltpu.SMEM)
+    return pl.BlockSpec((rows, block_n), lambda j, k: (0, k),
+                        memory_space=pltpu.SMEM)
 
 
 # -- fused single-call streamed grid ----------------------------------------
@@ -227,43 +307,10 @@ def fused_lhs_spec(rows: int, block_n: int, num_n: int):
     """BlockSpec for the stacked (rows, N) shared LHS on the fused grid:
     the descend phase MIRRORS the ascend walk (``min(k, 2*num_n-1-k)``
     is 0..num_n-1 then num_n-1..0), so the single stored LHS copy streams
-    through VMEM exactly once per phase with no refetch at the turn."""
+    through SMEM exactly once per phase with no refetch at the turn."""
     return pl.BlockSpec((rows, block_n),
-                        lambda j, k: (0, _imin(k, 2 * num_n - 1 - k)))
-
-
-def fused_vmem_working_set(n: int, block_n: int, block_m: int,
-                           n_chunk_blocks: int, n_lhs_vecs: int,
-                           n_carry: int, n_sweep_blocks: int,
-                           itemsize: int = 4,
-                           compute_itemsize: int | None = None) -> int:
-    """Bytes of VMEM a FUSED streamed grid step holds: the chunked
-    operand/out blocks + chunked LHS (at the storage itemsize) + the carry
-    rows and the full-N intermediate scratch that replaces the two-call
-    pair's HBM round trip (at the fp32 compute itemsize)."""
-    if compute_itemsize is None:
-        compute_itemsize = itemsize
-    return ((n_chunk_blocks * block_n * block_m + n_lhs_vecs * block_n)
-            * itemsize
-            + (n_carry * block_m + n_sweep_blocks * n * block_m)
-            * compute_itemsize)
-
-
-def check_vmem_fused(n: int, block_n: int, block_m: int, n_chunk_blocks: int,
-                     n_lhs_vecs: int, n_carry: int, n_sweep_blocks: int,
-                     itemsize: int = 4,
-                     compute_itemsize: int | None = None) -> None:
-    ws = fused_vmem_working_set(n, block_n, block_m, n_chunk_blocks,
-                                n_lhs_vecs, n_carry, n_sweep_blocks,
-                                itemsize=itemsize,
-                                compute_itemsize=compute_itemsize)
-    if ws > VMEM_BUDGET_BYTES:
-        raise ValueError(
-            f"fused streamed working set {ws/2**20:.1f} MiB exceeds VMEM "
-            f"budget ({VMEM_BUDGET_BYTES/2**20:.0f} MiB): N={n}, "
-            f"BLOCK_N={block_n}, BLOCK_M={block_m}. The full-N intermediate "
-            f"scratch does not fit — spill to the two-call streamed pair "
-            f"(fused=False) or reduce block_m.")
+                        lambda j, k: (0, _imin(k, 2 * num_n - 1 - k)),
+                        memory_space=pltpu.SMEM)
 
 
 def block_shape_of(block_spec) -> tuple:
@@ -271,6 +318,12 @@ def block_shape_of(block_spec) -> tuple:
     accessor for the static-analysis layer (``repro.analysis``), which
     enumerates kernel grids without running them."""
     return tuple(block_spec.block_shape)
+
+
+def in_smem(block_spec) -> bool:
+    """Whether a ``pl.BlockSpec`` places its block in SMEM (the shared LHS
+    and scalar parameters) rather than VMEM."""
+    return block_spec.memory_space == pltpu.SMEM
 
 
 def index_map_of(block_spec):

@@ -60,8 +60,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import chunk_lhs_spec, chunk_spec, fused_chunk_spec, \
-    fused_lhs_spec, _imin, reset_carry, row, scalar, store_row
+from .common import VMEM_LIMIT_BYTES, chunk_lhs_spec, chunk_spec, \
+    default_interpret, fused_chunk_spec, fused_lhs_spec, lhs_spec, \
+    param_spec, _imin, reset_carry, row, store_row, widen_rows
 
 # Sentinel coefficient source: the uniform-mode eps value, which rides in a
 # (1, 1) ARRAY operand (never a Python float baked into the kernel closure,
@@ -192,7 +193,7 @@ class SweepSpec:
 
     @property
     def carry_rows(self) -> int:
-        """Rows of the streamed forward kernel's VMEM carry scratch."""
+        """Rows of the (forward) kernel's VMEM carry scratch."""
         if self.layout == "batch":
             # factorisation carries: (c_hat, d_hat) or
             # (gamma x2, delta x2, g x2) lags
@@ -275,35 +276,37 @@ class SweepSpec:
     # -- derived accounting (no hand-kept tables) ---------------------------
 
     def storage_words(self, n: int, m: int) -> int:
-        """HBM<->VMEM words one solve READS from stored operands — the
-        factor/diagonals, the (streamed) RHS, and the eps parameter.
-        These are the words a ``storage_dtype`` override (bf16 in HBM,
-        fp32 in-kernel) shrinks; everything written moves at the compute
-        dtype and is counted by ``compute_words``."""
+        """HBM<->VMEM words one solve READS from the stored (N, M)
+        streams — the per-lane diagonals and the RHS.  These are the words
+        a ``storage_dtype`` override (bf16 in HBM, fp32 in-kernel)
+        shrinks; everything else moves at the compute dtype and is
+        counted by ``compute_words``."""
         if self.layout == "batch":
             # diagonals + rhs in.
             return (self.bandwidth + 1) * n * m
-        # rhs in; the two-call streamed pair re-reads the LHS for its
-        # backward kernel, the fused/resident variants read it once.
-        lhs_passes = 1 if (self.fused or not self.streamed) else 2
-        eps = 1 if self.uniform else 0
-        return n * m + lhs_passes * self.lhs_rows * n + eps
+        return n * m
 
     def compute_words(self, n: int, m: int) -> int:
         """HBM<->VMEM words moved at the COMPUTE dtype (fp32-accumulated,
-        regardless of ``storage_dtype``): the final x, plus — for the
-        two-call streamed pair only — the intermediate (and, for batch,
-        the spilled factor coefficients) round-tripped through HBM between
-        the forward and backward kernels.  Resident and fused variants
-        keep d_hat/g in VMEM, so their only compute-dtype stream is x."""
+        regardless of ``storage_dtype``): the final x; the shared factored
+        LHS and the eps parameter, which SMEM holds as 32-bit scalars; and
+        — for the two-call streamed pair only — the intermediate (and, for
+        batch, the spilled factor coefficients) round-tripped through HBM
+        between the forward and backward kernels.  Resident and fused
+        variants keep d_hat/g in VMEM, so their only (N, M) compute-dtype
+        stream is x."""
+        # the two-call streamed pair re-reads the LHS for its backward
+        # kernel, the fused/resident variants read it once.
+        lhs_passes = 1 if (self.fused or not self.streamed) else 2
+        lhs = lhs_passes * self.lhs_rows * n + (1 if self.uniform else 0)
         if not self.streamed or self.fused:
-            return n * m
+            return n * m + lhs
         if self.layout == "batch":
             # x out + fwd writes intermediate + n_coefs spills which the
             # bwd kernel reads back.
             return (1 + 2 * (1 + self.n_coefs)) * n * m
         # x out + the d_hat/g round trip.
-        return 3 * n * m
+        return 3 * n * m + lhs
 
     def traffic_words(self, n: int, m: int) -> int:
         """HBM<->VMEM words one solve of an (n, m) RHS moves — the roofline
@@ -337,29 +340,31 @@ class SweepSpec:
         return self.traffic_words(n, shard_lanes(m, n_shards))
 
     def vmem_counts(self) -> tuple:
-        """(n_rhs_blocks, n_lhs_vecs, n_carry_rows) for the VMEM budget
-        checks (``common.check_vmem`` / ``check_vmem_streamed`` /
-        ``check_vmem_fused``).  For the streamed batch pair this is the
-        FORWARD kernel's (larger) chunk working set: diagonals + rhs in,
-        intermediate + spilled coefs out.  The fused variants hold the
-        intermediate/spills in full-N VMEM scratch instead (counted
-        separately by ``sweep_scratch``), so their chunk-block count drops
-        back to operands in + x out."""
+        """(n_blocks, n_lhs_rows, n_carry_rows) for the fit checks
+        (``common.spec_working_set``): the lane-tiled blocks Pallas
+        pipelines (each double-buffered in VMEM), the shared-LHS rows held
+        in SMEM, and the VMEM carry rows that seed (resident) or thread
+        (streamed) the sweep carries.  For the streamed batch pair this
+        is the FORWARD kernel's (larger) set: diagonals + rhs in,
+        intermediate + spilled coefs out.  Full-N scratch is counted
+        separately by ``sweep_scratch``."""
         if self.layout == "shared":
-            return 2, self.lhs_rows, self.order
-        if self.fused:
-            return self.bandwidth + 2, 0, self.carry_rows
-        blocks = self.bandwidth + 1 + 1 + self.n_coefs
-        return blocks, 0, self.carry_rows
+            return 2, self.lhs_rows, self.carry_rows
+        if self.streamed and not self.fused:
+            return self.bandwidth + 1 + 1 + self.n_coefs, 0, self.carry_rows
+        return self.bandwidth + 2, 0, self.carry_rows
 
     def sweep_scratch(self) -> int:
-        """Full-length (N, BLOCK_M) VMEM scratch arrays a fused kernel
-        keeps resident across its ascend/descend walk — the intermediate
-        d_hat/g (plus, for batch layout, the factor coefficients) that the
-        two-call pair would spill to HBM.  0 for every non-fused spec."""
-        if not self.fused:
-            return 0
-        return 1 + self.n_coefs
+        """Full-length (N, BLOCK_M) VMEM scratch arrays a kernel keeps
+        across its whole sweep: the fused kernels' intermediate d_hat/g
+        (plus, for batch layout, the factor coefficients) that the two-call
+        pair would spill to HBM, and the resident batch kernel's factor
+        coefficients.  0 for every other spec."""
+        if self.fused:
+            return 1 + self.n_coefs
+        if self.layout == "batch" and not self.streamed:
+            return self.n_coefs
+        return 0
 
     @property
     def num_pallas_calls(self) -> int:
@@ -482,7 +487,7 @@ class RecurrenceSpec:
     def vmem_counts(self) -> tuple:
         """(n_rhs_blocks, n_lhs_vecs, n_carry_rows): gates + operand + h
         are all lane-tiled blocks; no shared LHS vectors; ``order`` carry
-        rows thread the streamed chunks."""
+        rows seed the resident sweep / thread the streamed chunks."""
         return self.order + 2, 0, self.order
 
     def sweep_scratch(self) -> int:
@@ -635,7 +640,7 @@ def _shared_coeff(lhs_ref, eps_ref):
     def at(src, i):
         if src == EPS_PARAM:
             return eps_ref[0, 0]
-        return scalar(lhs_ref, src, i)
+        return lhs_ref[src, i]
     return at
 
 
@@ -656,7 +661,7 @@ def _lane_coeff(refs, off=0):
     at_row = _shift(off)
 
     def at(src, i):
-        return row(refs[src], at_row(i), refs[src].shape[1])
+        return row(refs[src], at_row(i))
     return at
 
 
@@ -673,12 +678,11 @@ def _solve_pass(coeff_at, in_ref, out_ref, init, *, pspec: PassSpec,
     kernel's full-N intermediate scratch vs its BLOCK_N chunk walk);
     coefficient rows are always chunk-local (``coeff_at`` carries its own
     base when needed)."""
-    m = in_ref.shape[1]
     in_at, out_at = _shift(in_off), _shift(out_off)
 
     def body(t, carries):
         i = length - 1 - t if reverse else t
-        acc = row(in_ref, in_at(i), m)
+        acc = row(in_ref, in_at(i))
         for src, lag in pspec.terms:
             acc = acc - coeff_at(src, i) * carries[lag - 1]
         if pspec.scale is not None:
@@ -698,7 +702,6 @@ def _factor_pass(diag_at, rhs_ref, coef_store, out_ref, init, *, order: int,
     the general step: ``a_0``/``b_0`` only ever multiply zero carries, so
     no boundary special-casing — which is also what makes the streamed
     chunking and the identity sweep-padding exact."""
-    m = rhs_ref.shape[1]
     out_at = _shift(out_off)
 
     if order == 1:
@@ -708,7 +711,7 @@ def _factor_pass(diag_at, rhs_ref, coef_store, out_ref, init, *, order: int,
             inv = 1.0 / (diag_at(1, i) - a_i * chat_p)
             chat = diag_at(2, i) * inv
             coef_store(0, i, chat)
-            dh = (row(rhs_ref, i, m) - a_i * dh_p) * inv
+            dh = (row(rhs_ref, i) - a_i * dh_p) * inv
             store_row(out_ref, out_at(i), dh)
             return chat, dh
     else:
@@ -722,7 +725,7 @@ def _factor_pass(diag_at, rhs_ref, coef_store, out_ref, init, *, order: int,
             delta_i = diag_at(4, i) * inv
             coef_store(0, i, gamma_i)
             coef_store(1, i, delta_i)
-            g_i = (row(rhs_ref, i, m) - a_i * gg2 - beta_i * gg1) * inv
+            g_i = (row(rhs_ref, i) - a_i * gg2 - beta_i * gg1) * inv
             store_row(out_ref, out_at(i), g_i)
             return gamma_i, g1, delta_i, dl1, g_i, gg1
 
@@ -737,38 +740,72 @@ def _compute_dtype(dtype):
     return jnp.promote_types(dtype, jnp.float32)
 
 
-def _compiler_params(prefetch: bool, interpret: bool) -> dict:
-    """Mosaic knobs for the streamed/fused 2-D grids.  ``prefetch=True``
-    marks the lane axis ``parallel`` (the N-chunk axis stays ``arbitrary``
-    — its carry scratch is sequential), letting the pipeline stage the
-    next chunk's operand DMA into the second VMEM buffer while the
-    current chunk computes.  Interpret mode (CPU CI) takes no compiler
-    params at all — the interpreter executes grid steps serially, so this
-    is also the interpret-safe fallback."""
+def _compiler_params(interpret: bool, *, streamed: bool,
+                     prefetch: bool = False) -> dict:
+    """Mosaic knobs for a compiled kernel: the scoped-VMEM limit the fit
+    checks budget against (``common.VMEM_LIMIT_BYTES``) and the grid's
+    dimension semantics.  Resident grids walk independent lane tiles
+    (``parallel``).  On the streamed/fused 2-D grids the N-chunk axis is
+    ``arbitrary`` (its carry scratch is sequential); ``prefetch=True``
+    marks the lane axis ``parallel`` too.  Interpret mode (CPU CI) takes
+    no compiler params at all."""
     if interpret:
         return {}
-    sem = ("parallel", "arbitrary") if prefetch else ("arbitrary",
-                                                      "arbitrary")
-    return {"compiler_params":
-            pltpu.TPUCompilerParams(dimension_semantics=sem)}
+    if not streamed:
+        sem = ("parallel",)
+    else:
+        sem = ("parallel" if prefetch else "arbitrary", "arbitrary")
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=sem, vmem_limit_bytes=VMEM_LIMIT_BYTES)}
+
+
+def _resolve_interpret(interpret):
+    """``None`` resolves to the platform default: compiled on a TPU, the
+    Pallas interpreter elsewhere (``common.default_interpret``)."""
+    return default_interpret() if interpret is None else interpret
 
 
 # ---------------------------------------------------------------------------
 # Shared-layout kernels (one factored LHS, broadcast to every lane)
 # ---------------------------------------------------------------------------
+#
+# The stacked LHS lives in SMEM (``common.lhs_spec``): each sweep step reads
+# its coefficients as scalars and broadcasts them across the lane tile.
+# When the RHS arrives at a packed storage dtype (bf16), the kernel first
+# widens the block into its compute-dtype output (or the fused kernel's
+# intermediate scratch) and sweeps that copy in place.
+
+def _stored_input(in_ref, work_ref, off=0):
+    """The ref the first pass reads: ``in_ref`` itself when it is already
+    at the compute dtype, else ``work_ref`` after widening ``in_ref`` into
+    rows ``off:`` of it."""
+    if in_ref.dtype == work_ref.dtype:
+        return in_ref, 0
+    widen_rows(in_ref, work_ref, off)
+    return work_ref, off
+
+
+def _zero_carries(carry_ref, count: int) -> tuple:
+    """Zero the carry scratch and load ``count`` rows of it: the zero seed
+    of a resident sweep.  Loaded rather than built as a constant, so the
+    seed has the row layout of the values the sweep loop carries (Mosaic
+    cannot carry a broadcast constant and a loaded row in one slot)."""
+    carry_ref[...] = jnp.zeros_like(carry_ref)
+    return tuple(row(carry_ref, j) for j in range(count))
+
 
 def _shared_resident_kernel(*refs, spec: SweepSpec, n: int, unroll: int):
     """Both passes in one kernel; the output block doubles as intermediate
     storage (forward writes d_hat/g, backward overwrites with x)."""
     if spec.uniform:
-        eps_ref, lhs_ref, in_ref, x_ref = refs
+        eps_ref, lhs_ref, in_ref, x_ref, carry_ref = refs
     else:
-        (lhs_ref, in_ref, x_ref), eps_ref = refs, None
+        (lhs_ref, in_ref, x_ref, carry_ref), eps_ref = refs, None
     fwd, bwd = spec.passes()
     at = _shared_coeff(lhs_ref, eps_ref)
-    m = in_ref.shape[1]
-    zeros = (jnp.zeros((m,), x_ref.dtype),) * spec.order
-    _solve_pass(at, in_ref, x_ref, zeros, pspec=fwd, order=spec.order,
+    src, _ = _stored_input(in_ref, x_ref)
+    zeros = _zero_carries(carry_ref, spec.order)
+    _solve_pass(at, src, x_ref, zeros, pspec=fwd, order=spec.order,
                 length=n, reverse=False, unroll=unroll)
     _solve_pass(at, x_ref, x_ref, zeros, pspec=bwd, order=spec.order,
                 length=n, reverse=True, unroll=unroll)
@@ -782,10 +819,10 @@ def _shared_streamed_kernel(*refs, pspec: PassSpec, order: int, block_n: int,
         eps_ref, lhs_ref, in_ref, out_ref, carry_ref = refs
     else:
         (lhs_ref, in_ref, out_ref, carry_ref), eps_ref = refs, None
-    m = in_ref.shape[1]
     reset_carry(carry_ref, pl.program_id(1))
-    init = tuple(row(carry_ref, j, m) for j in range(order))
-    final = _solve_pass(_shared_coeff(lhs_ref, eps_ref), in_ref, out_ref,
+    init = tuple(row(carry_ref, j) for j in range(order))
+    src, _ = _stored_input(in_ref, out_ref)
+    final = _solve_pass(_shared_coeff(lhs_ref, eps_ref), src, out_ref,
                         init, pspec=pspec, order=order, length=block_n,
                         reverse=reverse, unroll=unroll)
     for j in range(order):
@@ -807,10 +844,9 @@ def _shared_fused_kernel(*refs, spec: SweepSpec, block_n: int, num_n: int,
         (lhs_ref, in_ref, x_ref, mid_ref, carry_ref), eps_ref = refs, None
     fwd, bwd = spec.passes()
     at = _shared_coeff(lhs_ref, eps_ref)
-    m = in_ref.shape[1]
     k = pl.program_id(1)
     reset_carry(carry_ref, k % num_n)
-    init = tuple(row(carry_ref, j, m) for j in range(spec.order))
+    init = tuple(row(carry_ref, j) for j in range(spec.order))
     # Base rows into the full-N scratch: the chunk this step ascends into /
     # descends from (clamped like the index maps, so the not-taken branch
     # never addresses out of range).
@@ -819,9 +855,10 @@ def _shared_fused_kernel(*refs, spec: SweepSpec, block_n: int, num_n: int,
 
     @pl.when(k < num_n)
     def _ascend():
-        final = _solve_pass(at, in_ref, mid_ref, init, pspec=fwd,
+        src, in_off = _stored_input(in_ref, mid_ref, off)
+        final = _solve_pass(at, src, mid_ref, init, pspec=fwd,
                             order=spec.order, length=block_n, reverse=False,
-                            unroll=unroll, out_off=off)
+                            unroll=unroll, in_off=in_off, out_off=off)
         for j in range(spec.order):
             store_row(carry_ref, j, final[j])
 
@@ -840,23 +877,26 @@ def shared_solver(spec: SweepSpec):
     ``solver(lhs, rhs, *, block_m, [block_n,] unroll, interpret, eps)``.
 
     ``lhs`` is the (rows, N) stack of ``repro.kernels.ops.stack_*_lhs``
-    (pre-shifted for transposed specs); ``eps`` is the (1, 1) uniform
-    parameter operand.  Callers pad: M % block_m == 0, and for streamed
-    specs N % block_n == 0."""
+    (pre-shifted for transposed specs) at the compute dtype; ``eps`` is
+    the (1, 1) uniform parameter operand.  ``rhs`` may arrive at a packed
+    storage dtype (bf16); the result is at the fp32-promoted compute
+    dtype.  Callers pad: M % block_m == 0, and for streamed specs
+    N % block_n == 0.  ``interpret=None`` compiles on a TPU and interprets
+    elsewhere."""
     assert spec.layout == "shared"
 
     if not spec.streamed:
         @functools.partial(jax.jit,
                            static_argnames=("block_m", "unroll", "interpret"))
-        def solver(lhs, rhs, *, block_m=128, unroll=1, interpret=True,
+        def solver(lhs, rhs, *, block_m=128, unroll=1, interpret=None,
                    eps=None):
+            interpret = _resolve_interpret(interpret)
             n, m = rhs.shape
             cdt = _compute_dtype(rhs.dtype)
-            in_specs = [pl.BlockSpec((spec.lhs_rows, n), lambda j: (0, 0)),
-                        _col_spec(n, block_m)]
+            in_specs = [lhs_spec(spec.lhs_rows, n), _col_spec(n, block_m)]
             args = [lhs, rhs]
             if spec.uniform:
-                in_specs.insert(0, pl.BlockSpec((1, 1), lambda j: (0, 0)))
+                in_specs.insert(0, param_spec())
                 args.insert(0, eps)
             return pl.pallas_call(
                 functools.partial(_shared_resident_kernel, spec=spec, n=n,
@@ -864,8 +904,10 @@ def shared_solver(spec: SweepSpec):
                 grid=(m // block_m,),
                 in_specs=in_specs,
                 out_specs=_col_spec(n, block_m),
-                out_shape=jax.ShapeDtypeStruct((n, m), cdt),
+                out_shape=_out_struct((n, m), cdt, *args),
+                scratch_shapes=[pltpu.VMEM((spec.order, block_m), cdt)],
                 interpret=interpret,
+                **_compiler_params(interpret, streamed=False),
             )(*args)
         return solver
 
@@ -874,7 +916,8 @@ def shared_solver(spec: SweepSpec):
                            static_argnames=("block_m", "block_n", "unroll",
                                             "interpret", "prefetch"))
         def solver(lhs, rhs, *, block_m=128, block_n=512, unroll=1,
-                   interpret=True, eps=None, prefetch=False):
+                   interpret=None, eps=None, prefetch=False):
+            interpret = _resolve_interpret(interpret)
             n, m = rhs.shape
             cdt = _compute_dtype(rhs.dtype)
             num_n = n // block_n
@@ -883,7 +926,7 @@ def shared_solver(spec: SweepSpec):
                                          phase="ascend")]
             args = [lhs, rhs]
             if spec.uniform:
-                in_specs.insert(0, pl.BlockSpec((1, 1), lambda j, k: (0, 0)))
+                in_specs.insert(0, param_spec())
                 args.insert(0, eps)
             return pl.pallas_call(
                 functools.partial(_shared_fused_kernel, spec=spec,
@@ -893,11 +936,12 @@ def shared_solver(spec: SweepSpec):
                 in_specs=in_specs,
                 out_specs=fused_chunk_spec(block_n, block_m, num_n,
                                            phase="descend"),
-                out_shape=jax.ShapeDtypeStruct((n, m), cdt),
+                out_shape=_out_struct((n, m), cdt, *args),
                 scratch_shapes=[pltpu.VMEM((n, block_m), cdt),
                                 pltpu.VMEM((spec.order, block_m), cdt)],
                 interpret=interpret,
-                **_compiler_params(prefetch, interpret),
+                **_compiler_params(interpret, streamed=True,
+                                   prefetch=prefetch),
             )(*args)
         return solver
 
@@ -905,7 +949,8 @@ def shared_solver(spec: SweepSpec):
                                                  "unroll", "interpret",
                                                  "prefetch"))
     def solver(lhs, rhs, *, block_m=128, block_n=512, unroll=1,
-               interpret=True, eps=None, prefetch=False):
+               interpret=None, eps=None, prefetch=False):
+        interpret = _resolve_interpret(interpret)
         n, m = rhs.shape
         cdt = _compute_dtype(rhs.dtype)
         num_n = n // block_n
@@ -919,7 +964,7 @@ def shared_solver(spec: SweepSpec):
                         chunk_spec(block_n, block_m, num_n, reverse=reverse)]
             args = [lhs, operand]
             if spec.uniform:
-                in_specs.insert(0, pl.BlockSpec((1, 1), lambda j, k: (0, 0)))
+                in_specs.insert(0, param_spec())
                 args.insert(0, eps)
             return pl.pallas_call(
                 functools.partial(_shared_streamed_kernel, pspec=pspec,
@@ -929,10 +974,11 @@ def shared_solver(spec: SweepSpec):
                 grid=grid,
                 in_specs=in_specs,
                 out_specs=chunk_spec(block_n, block_m, num_n, reverse=reverse),
-                out_shape=jax.ShapeDtypeStruct((n, m), cdt),
+                out_shape=_out_struct((n, m), cdt, *args),
                 scratch_shapes=carry,
                 interpret=interpret,
-                **_compiler_params(prefetch, interpret),
+                **_compiler_params(interpret, streamed=True,
+                                   prefetch=prefetch),
             )(*args)
 
         mid = one_pass(fwd, False, rhs)           # ascending: d_hat / g
@@ -947,15 +993,13 @@ def shared_solver(spec: SweepSpec):
 def _batch_resident_kernel(*refs, spec: SweepSpec, n: int, unroll: int):
     nd = spec.bandwidth
     diag_refs, rhs_ref, x_ref = refs[:nd], refs[nd], refs[nd + 1]
-    coef_refs = refs[nd + 2:]                     # VMEM scratch
-    m = rhs_ref.shape[1]
-    zeros = jnp.zeros((m,), x_ref.dtype)
+    coef_refs, carry_ref = refs[nd + 2:-1], refs[-1]   # VMEM scratch
+    zeros = _zero_carries(carry_ref, spec.carry_rows)
     _factor_pass(_lane_coeff(diag_refs), rhs_ref,
                  lambda r, i, v: store_row(coef_refs[r], i, v),
-                 x_ref, (zeros,) * spec.carry_rows, order=spec.order,
-                 length=n, unroll=unroll)
+                 x_ref, zeros, order=spec.order, length=n, unroll=unroll)
     _, bwd = spec.passes()
-    _solve_pass(_lane_coeff(coef_refs), x_ref, x_ref, (zeros,) * spec.order,
+    _solve_pass(_lane_coeff(coef_refs), x_ref, x_ref, zeros[:spec.order],
                 pspec=bwd, order=spec.order, length=n, reverse=True,
                 unroll=unroll)
 
@@ -970,9 +1014,8 @@ def _batch_streamed_fwd_kernel(*refs, spec: SweepSpec, block_n: int,
     out_ref = refs[nd + 1]
     coef_refs = refs[nd + 2:nd + 2 + spec.n_coefs]   # HBM-backed outputs
     carry_ref = refs[-1]
-    m = rhs_ref.shape[1]
     reset_carry(carry_ref, pl.program_id(1))
-    init = tuple(row(carry_ref, j, m) for j in range(spec.carry_rows))
+    init = tuple(row(carry_ref, j) for j in range(spec.carry_rows))
     final = _factor_pass(_lane_coeff(diag_refs), rhs_ref,
                          lambda r, i, v: store_row(coef_refs[r], i, v),
                          out_ref, init, order=spec.order, length=block_n,
@@ -988,10 +1031,9 @@ def _batch_streamed_bwd_kernel(*refs, spec: SweepSpec, block_n: int,
     coef_refs = refs[:spec.n_coefs]
     in_ref, x_ref, carry_ref = refs[spec.n_coefs], refs[spec.n_coefs + 1], \
         refs[-1]
-    m = in_ref.shape[1]
     reset_carry(carry_ref, pl.program_id(1))
     _, bwd = spec.passes()
-    init = tuple(row(carry_ref, j, m) for j in range(spec.order))
+    init = tuple(row(carry_ref, j) for j in range(spec.order))
     final = _solve_pass(_lane_coeff(coef_refs), in_ref, x_ref, init,
                         pspec=bwd, order=spec.order, length=block_n,
                         reverse=True, unroll=unroll)
@@ -1012,7 +1054,6 @@ def _batch_fused_kernel(*refs, spec: SweepSpec, block_n: int, num_n: int,
     mid_ref = refs[nd + 2]
     coef_refs = refs[nd + 3:nd + 3 + spec.n_coefs]   # full-N VMEM scratch
     carry_ref = refs[-1]
-    m = rhs_ref.shape[1]
     k = pl.program_id(1)
     reset_carry(carry_ref, k % num_n)
     off = _imin(k, num_n - 1) * block_n
@@ -1020,7 +1061,7 @@ def _batch_fused_kernel(*refs, spec: SweepSpec, block_n: int, num_n: int,
 
     @pl.when(k < num_n)
     def _ascend():
-        init = tuple(row(carry_ref, j, m) for j in range(spec.carry_rows))
+        init = tuple(row(carry_ref, j) for j in range(spec.carry_rows))
         final = _factor_pass(
             _lane_coeff(diag_refs), rhs_ref,
             lambda r, i, v: store_row(coef_refs[r], off + i, v),
@@ -1032,13 +1073,26 @@ def _batch_fused_kernel(*refs, spec: SweepSpec, block_n: int, num_n: int,
     @pl.when(k >= num_n)
     def _descend():
         _, bwd = spec.passes()
-        init = tuple(row(carry_ref, j, m) for j in range(spec.order))
+        init = tuple(row(carry_ref, j) for j in range(spec.order))
         final = _solve_pass(_lane_coeff(coef_refs, off=doff), mid_ref, x_ref,
                             init, pspec=bwd, order=spec.order,
                             length=block_n, reverse=True, unroll=unroll,
                             in_off=doff)
         for j in range(spec.order):
             store_row(carry_ref, j, final[j])
+
+
+def _check_batch_storage(args, interpret: bool) -> None:
+    """The batch kernels read every per-lane operand row by row, which a
+    packed (bf16) block does not allow on the chip: refuse it there rather
+    than widen the operands outside the kernel and run fp32 in silence."""
+    cdt = _compute_dtype(args[-1].dtype)
+    if not interpret and any(a.dtype != cdt for a in args):
+        raise NotImplementedError(
+            "storage_dtype for batch (per-system LHS) operands has no "
+            "compiled kernel: the batch sweeps load single rows of every "
+            "diagonal, which Mosaic cannot do on a packed bf16 block. Store "
+            "the diagonals at the compute dtype (storage_dtype=None).")
 
 
 @functools.lru_cache(maxsize=None)
@@ -1048,13 +1102,16 @@ def batch_solver(spec: SweepSpec):
 
     Callers pad lanes (identity main diagonal) and, for streamed specs,
     the sweep axis (identity main diagonal there too — the fused
-    factorisation divides in-kernel, see ``common.pad_sweep``)."""
+    factorisation divides in-kernel, see ``common.pad_sweep``).
+    ``interpret=None`` compiles on a TPU and interprets elsewhere."""
     assert spec.layout == "batch"
 
     if not spec.streamed:
         @functools.partial(jax.jit,
                            static_argnames=("block_m", "unroll", "interpret"))
-        def solver(*args, block_m=128, unroll=1, interpret=True):
+        def solver(*args, block_m=128, unroll=1, interpret=None):
+            interpret = _resolve_interpret(interpret)
+            _check_batch_storage(args, interpret)
             n, m = args[-1].shape
             cdt = _compute_dtype(args[-1].dtype)
             sp = _col_spec(n, block_m)
@@ -1064,10 +1121,13 @@ def batch_solver(spec: SweepSpec):
                 grid=(m // block_m,),
                 in_specs=[sp] * (spec.bandwidth + 1),
                 out_specs=sp,
-                out_shape=jax.ShapeDtypeStruct((n, m), cdt),
+                out_shape=_out_struct((n, m), cdt, *args),
                 scratch_shapes=[pltpu.VMEM((n, block_m), cdt)
-                                for _ in range(spec.n_coefs)],
+                                for _ in range(spec.n_coefs)]
+                               + [pltpu.VMEM((spec.carry_rows, block_m),
+                                             cdt)],
                 interpret=interpret,
+                **_compiler_params(interpret, streamed=False),
             )(*args)
         return solver
 
@@ -1075,8 +1135,10 @@ def batch_solver(spec: SweepSpec):
         @functools.partial(jax.jit,
                            static_argnames=("block_m", "block_n", "unroll",
                                             "interpret", "prefetch"))
-        def solver(*args, block_m=128, block_n=512, unroll=1, interpret=True,
+        def solver(*args, block_m=128, block_n=512, unroll=1, interpret=None,
                    prefetch=False):
+            interpret = _resolve_interpret(interpret)
+            _check_batch_storage(args, interpret)
             n, m = args[-1].shape
             cdt = _compute_dtype(args[-1].dtype)
             num_n = n // block_n
@@ -1089,27 +1151,31 @@ def batch_solver(spec: SweepSpec):
                 in_specs=[asc] * (spec.bandwidth + 1),
                 out_specs=fused_chunk_spec(block_n, block_m, num_n,
                                            phase="descend"),
-                out_shape=jax.ShapeDtypeStruct((n, m), cdt),
+                out_shape=_out_struct((n, m), cdt, *args),
                 scratch_shapes=[pltpu.VMEM((n, block_m), cdt)
                                 for _ in range(1 + spec.n_coefs)]
                                + [pltpu.VMEM((spec.carry_rows, block_m),
                                              cdt)],
                 interpret=interpret,
-                **_compiler_params(prefetch, interpret),
+                **_compiler_params(interpret, streamed=True,
+                                   prefetch=prefetch),
             )(*args)
         return solver
 
     @functools.partial(jax.jit, static_argnames=("block_m", "block_n",
                                                  "unroll", "interpret",
                                                  "prefetch"))
-    def solver(*args, block_m=128, block_n=512, unroll=1, interpret=True,
+    def solver(*args, block_m=128, block_n=512, unroll=1, interpret=None,
                prefetch=False):
+        interpret = _resolve_interpret(interpret)
+        _check_batch_storage(args, interpret)
         n, m = args[-1].shape
         cdt = _compute_dtype(args[-1].dtype)
         num_n = n // block_n
         grid = (m // block_m, num_n)
         csp = chunk_spec(block_n, block_m, num_n)
-        shape = jax.ShapeDtypeStruct((n, m), cdt)
+        shape = _out_struct((n, m), cdt, *args)
+        params = _compiler_params(interpret, streamed=True, prefetch=prefetch)
 
         outs = pl.pallas_call(
             functools.partial(_batch_streamed_fwd_kernel, spec=spec,
@@ -1120,7 +1186,7 @@ def batch_solver(spec: SweepSpec):
             out_shape=[shape] * (1 + spec.n_coefs),
             scratch_shapes=[pltpu.VMEM((spec.carry_rows, block_m), cdt)],
             interpret=interpret,
-            **_compiler_params(prefetch, interpret),
+            **params,
         )(*args)
         mid, coefs = outs[0], outs[1:]
 
@@ -1134,7 +1200,7 @@ def batch_solver(spec: SweepSpec):
             out_shape=shape,
             scratch_shapes=[pltpu.VMEM((spec.order, block_m), cdt)],
             interpret=interpret,
-            **_compiler_params(prefetch, interpret),
+            **params,
         )(*coefs, mid)
     return solver
 
@@ -1144,12 +1210,12 @@ def batch_solver(spec: SweepSpec):
 # ---------------------------------------------------------------------------
 
 def _gate_coeff(refs):
-    """Coefficient accessor for the recurrence layout: a (BLOCK_M,) gate
-    vector per sweep row, read NEGATED from per-token (N, BLOCK_M) refs —
+    """Coefficient accessor for the recurrence layout: a (1, BLOCK_M) gate
+    row per sweep step, read NEGATED from per-token (N, BLOCK_M) refs —
     ``_solve_pass`` subtracts its terms, a recurrence adds, and IEEE
     negation is exact, so ``q - (-p)*h`` is bitwise ``q + p*h``."""
     def at(src, i):
-        return -row(refs[src], i, refs[src].shape[1])
+        return -row(refs[src], i)
     return at
 
 
@@ -1157,10 +1223,10 @@ def _recurrence_resident_kernel(*refs, spec: RecurrenceSpec, n: int,
                                 unroll: int):
     """The whole recurrence in one kernel: a single ``_solve_pass`` over
     the resident (N, BLOCK_M) tiles, walked forward or reverse."""
-    gate_refs, in_ref, out_ref = refs[:spec.order], refs[-2], refs[-1]
+    gate_refs, in_ref, out_ref, carry_ref = (refs[:spec.order], refs[-3],
+                                             refs[-2], refs[-1])
     (pspec,) = spec.passes()
-    m = in_ref.shape[1]
-    zeros = (jnp.zeros((m,), in_ref.dtype),) * spec.order
+    zeros = _zero_carries(carry_ref, spec.order)
     _solve_pass(_gate_coeff(gate_refs), in_ref, out_ref, zeros, pspec=pspec,
                 order=spec.order, length=n, reverse=spec.reverse,
                 unroll=unroll)
@@ -1176,9 +1242,8 @@ def _recurrence_streamed_kernel(*refs, spec: RecurrenceSpec, block_n: int,
     in_ref, out_ref, carry_ref = refs[spec.order], refs[spec.order + 1], \
         refs[-1]
     (pspec,) = spec.passes()
-    m = in_ref.shape[1]
     reset_carry(carry_ref, pl.program_id(1))
-    init = tuple(row(carry_ref, j, m) for j in range(spec.order))
+    init = tuple(row(carry_ref, j) for j in range(spec.order))
     final = _solve_pass(_gate_coeff(gate_refs), in_ref, out_ref, init,
                         pspec=pspec, order=spec.order, length=block_n,
                         reverse=spec.reverse, unroll=unroll)
@@ -1197,13 +1262,15 @@ def recurrence_solver(spec: RecurrenceSpec):
     (``repro.kernels.ops.recurrence``), which keeps the kernels on the
     same zero-carry protocol as every sweep kernel.  Callers pad:
     M % block_m == 0, and for streamed specs N % block_n == 0 (zero
-    padding is exact: padded gate rows multiply a finite carry by 0)."""
+    padding is exact: padded gate rows multiply a finite carry by 0).
+    ``interpret=None`` compiles on a TPU and interprets elsewhere."""
     assert isinstance(spec, RecurrenceSpec)
 
     if not spec.streamed:
         @functools.partial(jax.jit,
                            static_argnames=("block_m", "unroll", "interpret"))
-        def solver(*args, block_m=128, unroll=1, interpret=True):
+        def solver(*args, block_m=128, unroll=1, interpret=None):
+            interpret = _resolve_interpret(interpret)
             n, m = args[-1].shape
             sp = _col_spec(n, block_m)
             return pl.pallas_call(
@@ -1212,14 +1279,18 @@ def recurrence_solver(spec: RecurrenceSpec):
                 grid=(m // block_m,),
                 in_specs=[sp] * (spec.order + 1),
                 out_specs=sp,
-                out_shape=jax.ShapeDtypeStruct((n, m), args[-1].dtype),
+                out_shape=_out_struct((n, m), args[-1].dtype, *args),
+                scratch_shapes=[pltpu.VMEM((spec.order, block_m),
+                                           args[-1].dtype)],
                 interpret=interpret,
+                **_compiler_params(interpret, streamed=False),
             )(*args)
         return solver
 
     @functools.partial(jax.jit, static_argnames=("block_m", "block_n",
                                                  "unroll", "interpret"))
-    def solver(*args, block_m=128, block_n=512, unroll=1, interpret=True):
+    def solver(*args, block_m=128, block_n=512, unroll=1, interpret=None):
+        interpret = _resolve_interpret(interpret)
         n, m = args[-1].shape
         num_n = n // block_n
         csp = chunk_spec(block_n, block_m, num_n, reverse=spec.reverse)
@@ -1229,12 +1300,22 @@ def recurrence_solver(spec: RecurrenceSpec):
             grid=(m // block_m, num_n),
             in_specs=[csp] * (spec.order + 1),
             out_specs=csp,
-            out_shape=jax.ShapeDtypeStruct((n, m), args[-1].dtype),
+            out_shape=_out_struct((n, m), args[-1].dtype, *args),
             scratch_shapes=[pltpu.VMEM((spec.order, block_m),
                                        args[-1].dtype)],
             interpret=interpret,
+            **_compiler_params(interpret, streamed=True),
         )(*args)
     return solver
+
+
+def _out_struct(shape, dtype, *operands):
+    """Output type of a kernel: varying over every mesh axis any operand
+    varies over, so the kernels type-check inside ``jax.shard_map`` with
+    ``check_vma=True`` (the ``sharded`` backend); outside it this is the
+    empty set."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _col_spec(n: int, block_m: int):

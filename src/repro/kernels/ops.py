@@ -26,9 +26,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import (PentaFactor, PeriodicPentaFactor,
                         PeriodicTridiagFactor, TridiagFactor)
-from .common import (canonical_storage_dtype, check_vmem, check_vmem_fused,
-                     check_vmem_streamed, default_interpret, pad_lanes,
-                     pad_sweep)
+from . import common as _common
+from .common import (LANE, canonical_storage_dtype, check_fits,
+                     default_interpret, pad_lanes, pad_sweep,
+                     smem_working_set, vmem_working_set)
 from .engine import (RecurrenceSpec, SweepSpec, batch_solver,
                      find_recurrence_spec, find_spec, recurrence_solver,
                      shared_solver)
@@ -75,26 +76,12 @@ def stack_penta_lhs(f: PentaFactor, uniform: bool = False, *,
 
 
 def _check_spec_vmem(spec: SweepSpec, n: int, block_m: int,
-                     block_n: int | None, dtype,
-                     storage_dtype=None) -> None:
-    """Spec-derived working-set check (no hand-kept per-kernel counts).
-
-    Mixed-precision storage sizes the streamed chunk operands at the
-    storage itemsize and the carries / fused full-N intermediate scratch
-    at the fp32-promoted compute itemsize."""
-    n_rhs, n_lhs, n_carry = spec.vmem_counts()
-    c_item = jnp.promote_types(dtype, jnp.float32).itemsize
-    s_item = (storage_dtype or dtype).itemsize
-    if getattr(spec, "fused", False):
-        check_vmem_fused(n, block_n, block_m, n_rhs, n_lhs, n_carry,
-                         spec.sweep_scratch(), itemsize=s_item,
-                         compute_itemsize=c_item)
-    elif block_n is None:
-        check_vmem(n, block_m, n_rhs_blocks=n_rhs, n_lhs_vecs=n_lhs,
-                   itemsize=c_item)
-    else:
-        check_vmem_streamed(block_n, block_m, n_rhs, n_lhs, n_carry,
-                            itemsize=c_item)
+                     block_n: int | None, dtype) -> None:
+    """Spec-derived working-set check (no hand-kept per-kernel counts),
+    priced at the fp32-promoted compute itemsize — an upper bound for
+    blocks stored at a packed storage dtype."""
+    check_fits(spec, n, block_m, block_n,
+               itemsize=jnp.promote_types(dtype, jnp.float32).itemsize)
 
 
 def thomas_constant(f: TridiagFactor, d: jax.Array, *, block_m: int = 128,
@@ -110,9 +97,9 @@ def thomas_constant(f: TridiagFactor, d: jax.Array, *, block_m: int = 128,
     which lifts the VMEM wall for large N — or, with ``fused=True``, the
     single-call ascend/descend kernel that keeps the intermediate in VMEM
     (half the streamed HBM traffic).  ``storage_dtype="bf16"`` stores the
-    factor and RHS streams at bf16 in HBM (fp32 accumulation in-kernel;
-    the solve returns fp32).  ``prefetch=True`` double-buffers the chunk
-    DMA on hardware (no-op under interpret).  ``transposed=True`` solves
+    RHS stream at bf16 in HBM (fp32 factor and accumulation in-kernel;
+    the solve returns fp32).  ``prefetch=True`` marks the lane-tile grid
+    axis parallel on hardware (no-op under interpret).  ``transposed=True`` solves
     A^T x = d from the SAME stored factor (the adjoint sweeps)."""
     if interpret is None:
         interpret = default_interpret()
@@ -120,11 +107,11 @@ def thomas_constant(f: TridiagFactor, d: jax.Array, *, block_m: int = 128,
     sdt = canonical_storage_dtype(storage_dtype)
     spec = find_spec(3, "constant", streamed=block_n is not None,
                      transposed=transposed, fused=fused)
-    _check_spec_vmem(spec, n, block_m, block_n, d.dtype, sdt)
+    _check_spec_vmem(spec, n, block_m, block_n, d.dtype)
     lhs = stack_tridiag_lhs(f, transposed=transposed)
     d_pad, m = pad_lanes(d, block_m)
     if sdt is not None:
-        lhs, d_pad = lhs.astype(sdt), d_pad.astype(sdt)
+        d_pad = d_pad.astype(sdt)
     if block_n is None:
         x = shared_solver(spec)(lhs, d_pad, block_m=block_m, unroll=unroll,
                                 interpret=interpret)
@@ -152,14 +139,15 @@ def thomas_batch(a, b, c, d, *, block_m: int = 128,
     reason and spills the fused c_hat to HBM between the passes —
     ``fused=True`` keeps the spill in full-N VMEM scratch instead (one
     ascend/descend kernel).  ``storage_dtype="bf16"`` streams the
-    diagonals/RHS at bf16 (fp32 in-kernel); ``prefetch=True``
-    double-buffers the chunk DMA on hardware."""
+    diagonals/RHS at bf16 (fp32 in-kernel; interpret mode only — the
+    compiled batch kernels refuse packed operands); ``prefetch=True``
+    marks the lane-tile grid axis parallel on hardware."""
     if interpret is None:
         interpret = default_interpret()
     n, m = d.shape
     sdt = canonical_storage_dtype(storage_dtype)
     spec = find_spec(3, "batch", streamed=block_n is not None, fused=fused)
-    _check_spec_vmem(spec, n, block_m, block_n, d.dtype, sdt)
+    _check_spec_vmem(spec, n, block_m, block_n, d.dtype)
     idents = (False, True, False, False)          # b is the main diagonal
     args = [pad_lanes(x, block_m, identity=ident)[0]
             for x, ident in zip((a, b, c, d), idents)]
@@ -197,8 +185,8 @@ def penta_constant(f: PentaFactor, rhs: jax.Array, *, block_m: int = 128,
     cuPentUniformBatch when ``uniform``).  ``block_n`` selects the
     HBM-streamed split-N kernel pair (``fused=True``: the single-call
     ascend/descend kernel — half the streamed traffic);
-    ``storage_dtype="bf16"`` streams the factor/RHS at bf16 (fp32
-    in-kernel); ``transposed=True`` solves A^T x = rhs from the SAME
+    ``storage_dtype="bf16"`` streams the RHS at bf16 (fp32 factor and
+    carries in-kernel); ``transposed=True`` solves A^T x = rhs from the SAME
     stored factor."""
     if interpret is None:
         interpret = default_interpret()
@@ -207,12 +195,12 @@ def penta_constant(f: PentaFactor, rhs: jax.Array, *, block_m: int = 128,
     spec = find_spec(5, "uniform" if uniform else "constant",
                      streamed=block_n is not None, transposed=transposed,
                      fused=fused)
-    _check_spec_vmem(spec, n, block_m, block_n, rhs.dtype, sdt)
-    eps = _uniform_eps_param(f, sdt or rhs.dtype) if uniform else None
+    _check_spec_vmem(spec, n, block_m, block_n, rhs.dtype)
+    eps = _uniform_eps_param(f, rhs.dtype) if uniform else None
     lhs = stack_penta_lhs(f, uniform=uniform, transposed=transposed)
     rhs_pad, m = pad_lanes(rhs, block_m)
     if sdt is not None:
-        lhs, rhs_pad = lhs.astype(sdt), rhs_pad.astype(sdt)
+        rhs_pad = rhs_pad.astype(sdt)
     if block_n is None:
         x = shared_solver(spec)(lhs, rhs_pad, block_m=block_m,
                                 unroll=unroll, interpret=interpret, eps=eps)
@@ -235,13 +223,14 @@ def penta_batch(a, b, c, d, e, rhs, *, block_m: int = 128,
     ``block_n`` selects the streamed pair (gamma/delta spill to HBM);
     ``fused=True`` keeps the spill in full-N VMEM scratch instead (one
     ascend/descend kernel); ``storage_dtype="bf16"`` streams the
-    diagonals/RHS at bf16 (fp32 in-kernel)."""
+    diagonals/RHS at bf16 (fp32 in-kernel; interpret mode only — the
+    compiled batch kernels refuse packed operands)."""
     if interpret is None:
         interpret = default_interpret()
     n, m = rhs.shape
     sdt = canonical_storage_dtype(storage_dtype)
     spec = find_spec(5, "batch", streamed=block_n is not None, fused=fused)
-    _check_spec_vmem(spec, n, block_m, block_n, rhs.dtype, sdt)
+    _check_spec_vmem(spec, n, block_m, block_n, rhs.dtype)
     idents = (False, False, True, False, False, False)  # c is the main diag
     args = [pad_lanes(x, block_m, identity=ident)[0]
             for x, ident in zip((a, b, c, d, e, rhs), idents)]
@@ -318,6 +307,22 @@ def recurrence(*operands, h0=None, reverse: bool = False,
     return h[:n, :m]
 
 
+def _check_cn_vmem(n: int, block_m: int, lhs_rows: int, dtype) -> None:
+    """Fit check of the fused CN step kernels: the field in and out as
+    (N, BLOCK_M) blocks, plus the (N, <=4) correction directions, whose
+    block pads to one lane tile — all double-buffered — and the factored
+    LHS in SMEM."""
+    ws = (vmem_working_set(n, block_m, 2, itemsize=dtype.itemsize)
+          + vmem_working_set(n, LANE, 1, itemsize=dtype.itemsize))
+    if (ws > _common.VMEM_BUDGET_BYTES or smem_working_set(
+            lhs_rows, n, dtype.itemsize) > _common.SMEM_BUDGET_BYTES):
+        raise ValueError(
+            f"fused CN step working set {ws/2**20:.1f} MiB exceeds VMEM "
+            f"budget ({_common.VMEM_BUDGET_BYTES/2**20:.0f} MiB) or its "
+            f"LHS exceeds SMEM: N={n}, BLOCK_M={block_m}. Reduce block_m "
+            f"or N.")
+
+
 def fused_cn_step(pf: PeriodicTridiagFactor, sigma: float, c: jax.Array, *,
                   block_m: int = 128, unroll: int = 1,
                   interpret: bool | None = None) -> jax.Array:
@@ -325,8 +330,7 @@ def fused_cn_step(pf: PeriodicTridiagFactor, sigma: float, c: jax.Array, *,
     if interpret is None:
         interpret = default_interpret()
     n = c.shape[0]
-    check_vmem(n, block_m, n_rhs_blocks=2, n_lhs_vecs=4,
-               itemsize=c.dtype.itemsize)
+    _check_cn_vmem(n, block_m, 3, c.dtype)
     lhs = stack_tridiag_lhs(pf.factor)
     z = pf.z.reshape(n, 1)
     params = jnp.zeros((1, 8), c.dtype)
@@ -347,8 +351,7 @@ def fused_cn_penta_step(pf: PeriodicPentaFactor, sigma: float, c: jax.Array,
     if interpret is None:
         interpret = default_interpret()
     n = c.shape[0]
-    check_vmem(n, block_m, n_rhs_blocks=2, n_lhs_vecs=10,
-               itemsize=c.dtype.itemsize)
+    _check_cn_vmem(n, block_m, 5, c.dtype)
     lhs = stack_penta_lhs(pf.factor)
     params = jnp.zeros((1, 16), c.dtype)
     stencil = [-sigma, 4 * sigma, 1 - 6 * sigma, 4 * sigma, -sigma]
@@ -462,12 +465,10 @@ def sharded_solve(solve_fn, mesh: Mesh, batch_axes) -> callable:
     paper's storage saving, applied per-device). The solve needs no
     collectives — systems are independent.
     """
-    from jax.experimental.shard_map import shard_map
-
     spec_rhs = P(None, batch_axes)
-    fn = shard_map(solve_fn, mesh=mesh,
-                   in_specs=(P(), spec_rhs), out_specs=spec_rhs,
-                   check_rep=False)
+    fn = jax.shard_map(solve_fn, mesh=mesh,
+                       in_specs=(P(), spec_rhs), out_specs=spec_rhs,
+                       check_vma=True)
 
     def wrapped(factor, rhs):
         return fn(factor, rhs)

@@ -39,6 +39,8 @@ def main():
     import jax
     import jax.numpy as jnp
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.ckpt import AsyncWriter, latest_step, restore
     from repro.configs import get_config, get_smoke_config
     from repro.data import SyntheticLM

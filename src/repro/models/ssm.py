@@ -60,10 +60,12 @@ def _conv_step(buf: jax.Array, x_t: jax.Array, w: jax.Array):
     return y, full[:, 1:]
 
 
-def ssd_chunked(xh, dt, A_log, Bm, Cm, chunk: int):
+def ssd_chunked(xh, dt, A_log, Bm, Cm, chunk: int, method: str = "auto"):
     """Chunked SSD scan.
 
     xh: (B, S, H, P); dt: (B, S, H) post-softplus; Bm, Cm: (B, S, N).
+    ``method`` runs the inter-chunk recurrence (``core.linear_recurrence``;
+    the model uses ``"auto"``, the Pallas kernels for float operands).
     Returns (y (B, S, H, P), final_state (B, H, P, N)).
     """
     B, S, H, P = xh.shape
@@ -104,7 +106,7 @@ def ssd_chunked(xh, dt, A_log, Bm, Cm, chunk: int):
     # auto policy: the engine's gated-recurrence Pallas kernels; the
     # per-chunk decay broadcasts to a full gate operand on dispatch
     # (fp32 carries — everything above is fp32 already)
-    S_run = linear_recurrence(p_t, q_t, method="auto")       # inclusive prefix
+    S_run = linear_recurrence(p_t, q_t, method=method)       # inclusive prefix
     S_prev = jnp.concatenate([jnp.zeros_like(S_run[:1]), S_run[:-1]], axis=0)
     S_prev = jnp.moveaxis(S_prev, 0, 1)                      # (B, nc, H, P, N)
 

@@ -60,10 +60,10 @@ def make_compressed_mean(mesh: Mesh, axis: str):
                 treedef.unflatten([o[1] for o in out]))
 
     def mean_c(stacked_tree, err_tree):
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
-            in_specs=(P(axis), P(axis)), out_specs=(P(axis), P(axis)))
+            in_specs=(P(axis), P(axis)), out_specs=(P(axis), P(axis)),
+            check_vma=True)
         return fn(stacked_tree, err_tree)
 
     return mean_c

@@ -53,19 +53,22 @@ def pipeline_run(mesh: Mesh, axis: str, stage_fn, stage_params, x_mb):
             buf_next = jax.lax.ppermute(y, axis, perm)
             return (buf_next, outs), None
 
-        buf0 = jnp.zeros_like(xs[0])
-        outs0 = jnp.zeros_like(xs)
+        # the carries vary over the stage axis (each stage holds its own)
+        buf0 = jax.lax.pcast(jnp.zeros_like(xs[0]), axis, to="varying")
+        outs0 = jax.lax.pcast(jnp.zeros_like(xs), axis, to="varying")
         (_, outs), _ = jax.lax.scan(step, (buf0, outs0), jnp.arange(T))
         # only the last stage's collection is meaningful; replicate it
         outs = jnp.where(idx == K - 1, outs, jnp.zeros_like(outs))
         return jax.lax.psum(outs, axis)
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis), P()), out_specs=P(),
-        check_rep=False)
-    return fn(stage_params, x_mb)
+        check_vma=True)
+    # a mesh with Explicit axes (``jax.make_mesh``'s default) types the
+    # per-stage indexing inside the body only under a mesh context
+    with jax.set_mesh(mesh):
+        return fn(stage_params, x_mb)
 
 
 def bubble_fraction(n_stages: int, n_microbatches: int) -> float:

@@ -61,28 +61,10 @@ def _spec_for(system: BandedSystem, *, streamed: bool,
                               streamed=streamed, fused=fused)
 
 
-def _itemsizes(system: BandedSystem, storage_dtype=None) -> tuple:
-    """(storage itemsize, compute itemsize) for the working-set math —
-    stored operands move/sit at the (possibly bf16) storage dtype, carries
-    and the fused intermediate scratch at the fp32-promoted compute
-    dtype."""
-    sdt = _kcommon.canonical_storage_dtype(storage_dtype)
-    c_item = jnp.promote_types(system.dtype, jnp.float32).itemsize
-    s_item = (sdt or jnp.dtype(system.dtype)).itemsize
-    return s_item, c_item
-
-
-def _vmem_counts(system: BandedSystem) -> tuple:
-    """(n_rhs_blocks, n_lhs_vecs) of the RESIDENT kernel serving this
-    system — derived from its ``SweepSpec`` (matches the checks in
-    ``repro.kernels.ops``)."""
-    n_rhs, n_lhs, _ = _spec_for(system, streamed=False).vmem_counts()
-    return n_rhs, n_lhs
-
-
-def _streamed_counts(system: BandedSystem) -> tuple:
-    """(n_rhs_blocks, n_lhs_vecs, n_carry) of the streamed kernel pair."""
-    return _spec_for(system, streamed=True).vmem_counts()
+def _itemsize(system: BandedSystem) -> int:
+    """The fp32-promoted compute itemsize the fit checks price blocks at
+    (an upper bound for blocks kept at a packed storage dtype)."""
+    return jnp.promote_types(system.dtype, jnp.float32).itemsize
 
 
 def _lane_cap(system: BandedSystem) -> int | None:
@@ -92,65 +74,51 @@ def _lane_cap(system: BandedSystem) -> int | None:
     return -(-system.batch // _kcommon.LANE) * _kcommon.LANE
 
 
-def auto_block_m(system: BandedSystem) -> int | None:
-    """Largest candidate lane tile whose RESIDENT (full-N) working set fits
-    the VMEM budget (None if even the smallest does not fit)."""
-    n_rhs, n_lhs = _vmem_counts(system)
-    itemsize = jnp.dtype(system.dtype).itemsize
+def _lane_tiles(system: BandedSystem) -> tuple:
+    """Candidate lane tiles, widest first, capped at the lane-padded batch."""
     cap = _lane_cap(system)
-    for bm in _BLOCK_M_CANDIDATES:
-        if cap is not None and bm > max(cap, _BLOCK_M_CANDIDATES[-1]):
-            continue
-        ws = _kcommon.vmem_working_set(system.n, bm, n_rhs, n_lhs,
-                                       itemsize=itemsize)
-        if ws <= _kcommon.VMEM_BUDGET_BYTES:
-            return bm
-    return None
+    return tuple(bm for bm in _BLOCK_M_CANDIDATES
+                 if cap is None or bm <= max(cap, _BLOCK_M_CANDIDATES[-1]))
 
 
-def _streamed_fits(system: BandedSystem, block_m: int, block_n: int, *,
-                   prefetch: bool = False,
-                   storage_dtype=None) -> bool:
-    n_rhs, n_lhs, n_carry = _streamed_counts(system)
-    if prefetch:
-        # double-buffered chunk DMA: while chunk k computes, chunk k+1's
-        # operand AND shared-LHS blocks stage into a second buffer — the
-        # tuner must budget for both copies being resident at once
-        n_rhs, n_lhs = 2 * n_rhs, 2 * n_lhs
-    s_item, _ = _itemsizes(system, storage_dtype)
-    ws = _kcommon.streamed_vmem_working_set(
-        block_n, block_m, n_rhs, n_lhs, n_carry, itemsize=s_item)
-    return ws <= _kcommon.VMEM_BUDGET_BYTES
+def _fits(system: BandedSystem, block_m: int, block_n: int | None = None,
+          *, fused: bool = False) -> bool:
+    """Does the kernel serving ``system`` at ``(block_m, block_n)`` fit the
+    VMEM and SMEM budgets?  One spec-derived model
+    (``common.spec_working_set``) for resident, two-call streamed and
+    fused kernels alike."""
+    spec = _spec_for(system, streamed=block_n is not None, fused=fused)
+    return _kcommon.spec_fits(spec, system.n, block_m, block_n,
+                              itemsize=_itemsize(system))
 
 
-def _fused_fits(system: BandedSystem, block_m: int, block_n: int, *,
-                prefetch: bool = False, storage_dtype=None) -> bool:
+def _fused_fits(system: BandedSystem, block_m: int, block_n: int) -> bool:
     """Does the FUSED single-call sweep fit at ``(block_m, block_n)``?
 
     The fused kernel keeps the inter-pass intermediates (d_hat / the
     backward coefficients) for the current lane tile in full-N VMEM
     scratch — that scratch grows with N, so this is the spill rule: when
     it busts the budget the tuner falls back to the two-call pair."""
-    spec = _spec_for(system, streamed=True, fused=True)
-    n_rhs, n_lhs, n_carry = spec.vmem_counts()
-    if prefetch:
-        n_rhs, n_lhs = 2 * n_rhs, 2 * n_lhs
-    s_item, c_item = _itemsizes(system, storage_dtype)
-    ws = _kcommon.fused_vmem_working_set(
-        system.n, block_n, block_m, n_rhs, n_lhs, n_carry,
-        spec.sweep_scratch(), itemsize=s_item, compute_itemsize=c_item)
-    return ws <= _kcommon.VMEM_BUDGET_BYTES
+    return _fits(system, block_m, block_n, fused=True)
+
+
+def auto_block_m(system: BandedSystem) -> int | None:
+    """Largest candidate lane tile whose RESIDENT (full-N) working set fits
+    the budgets (None if even the smallest does not fit)."""
+    for bm in _lane_tiles(system):
+        if _fits(system, bm):
+            return bm
+    return None
 
 
 def _pick_fused(system: BandedSystem, block_m: int, block_n: int, *,
-                prefetch: bool = False, storage_dtype=None) -> bool:
+                storage_dtype=None) -> bool:
     """Cost-model leg of the auto-tuner, once streaming is resolved: among
     the streamed variants that FIT at ``(block_m, block_n)`` — the two-call
     pair always, the fused single-call kernel when its full-N scratch fits
     — pick the one the traffic model says moves the fewest HBM bytes."""
     candidates = [False]
-    if _fused_fits(system, block_m, block_n, prefetch=prefetch,
-                   storage_dtype=storage_dtype):
+    if _fused_fits(system, block_m, block_n):
         candidates.append(True)
     sdt = _kcommon.canonical_storage_dtype(storage_dtype)
     m = system.batch if system.batch is not None else _kcommon.LANE
@@ -159,100 +127,82 @@ def _pick_fused(system: BandedSystem, block_m: int, block_n: int, *,
             system.n, m, system.dtype, sdt))
 
 
-def _fused_block_n(system: BandedSystem, block_m: int, *,
-                   prefetch: bool = False,
-                   storage_dtype=None) -> int | None:
+def _fused_block_n(system: BandedSystem, block_m: int) -> int | None:
     """Largest N-chunk at which BOTH streamed variants fit at ``block_m``
-    — the fused kernel's full-N scratch plus doubled prefetch buffers
-    often need a smaller chunk than the two-call pair alone would pick."""
+    — the fused kernel's full-N scratch often needs a smaller chunk than
+    the two-call pair alone would pick."""
     for bn in _BLOCK_N_CANDIDATES:
-        if (_streamed_fits(system, block_m, bn, prefetch=prefetch,
-                           storage_dtype=storage_dtype)
-                and _fused_fits(system, block_m, bn, prefetch=prefetch,
-                                storage_dtype=storage_dtype)):
+        if _fits(system, block_m, bn) and _fused_fits(system, block_m, bn):
             return bn
     return None
 
 
 def resolve_fused(system: BandedSystem, block_m: int, block_n: int | None, *,
-                  fused: bool | None = None, prefetch: bool = False,
-                  storage_dtype=None) -> bool:
+                  fused: bool | None = None, storage_dtype=None) -> bool:
     """Resolve the ``fused`` option once ``(block_m, block_n)`` is tuned.
 
     ``None`` (auto) runs the traffic-model argmin over the fitting
     streamed variants; explicit values pass through unchanged (the ops
-    layer's VMEM check raises if an explicit ``True`` busts the budget —
+    layer's fit check raises if an explicit ``True`` busts the budget —
     that error names the two-call spill rule).  Resident dispatch
     (``block_n=None``) has nothing to fuse.  Shared between this backend
     and the per-device ``sharded`` tuner."""
     if block_n is None:
         return False
     if fused is None:
-        return _pick_fused(system, block_m, block_n, prefetch=prefetch,
+        return _pick_fused(system, block_m, block_n,
                            storage_dtype=storage_dtype)
     return bool(fused)
 
 
-def auto_block_n(system: BandedSystem, block_m: int, *,
-                 prefetch: bool = False) -> int | None:
-    """Largest streamed N-chunk that fits the budget at ``block_m`` (None
+def auto_block_n(system: BandedSystem, block_m: int) -> int | None:
+    """Largest streamed N-chunk that fits the budgets at ``block_m`` (None
     if even the smallest does not fit)."""
     for bn in _BLOCK_N_CANDIDATES:
-        if _streamed_fits(system, block_m, bn, prefetch=prefetch):
+        if _fits(system, block_m, bn):
             return bn
     return None
 
 
 def auto_tune(system: BandedSystem, *, block_m: int | None = None,
-              block_n: int | None = None,
-              prefetch: bool = False) -> tuple | None:
+              block_n: int | None = None) -> tuple | None:
     """Resolve ``(block_m, block_n)``; ``block_n=None`` means resident.
 
     Resident is preferred (one pass, half the RHS traffic); the streamed
     split-N pair is the fallback that lifts the VMEM wall — for batch
     (per-system-LHS) systems as well as shared-LHS ones.  Explicit user
     choices are honoured when they fit, never silently overridden.
-    ``prefetch=True`` budgets the streamed candidates for double-buffered
-    chunk DMA (two resident copies of every chunk operand); whether the
-    streamed dispatch then runs FUSED (one call) or as the two-call pair
-    is resolved separately by ``_pick_fused`` — the 2-tuple contract of
-    this function is unchanged.
+    Whether the streamed dispatch then runs FUSED (one call) or as the
+    two-call pair is resolved separately by ``_pick_fused`` — the 2-tuple
+    contract of this function is unchanged.
     ``None`` also means "no kernel family at all" (periodic x batch —
     this check lives HERE so every tuner caller inherits it;
     ``supports()`` spells out the reason)."""
     if system.periodic and system.mode == "batch":
         return None
-    n_rhs, n_lhs = _vmem_counts(system)
-    itemsize = jnp.dtype(system.dtype).itemsize
     if block_n is not None:
         # explicit streaming request
         for bm in ((block_m,) if block_m is not None else _BLOCK_M_CANDIDATES):
-            if _streamed_fits(system, bm, block_n, prefetch=prefetch):
+            if _fits(system, bm, block_n):
                 return bm, block_n
         return None
     if block_m is not None:
-        ws = _kcommon.vmem_working_set(system.n, block_m, n_rhs, n_lhs,
-                                       itemsize=itemsize)
-        if ws <= _kcommon.VMEM_BUDGET_BYTES:
+        if _fits(system, block_m):
             return block_m, None
-        bn = auto_block_n(system, block_m, prefetch=prefetch)
+        bn = auto_block_n(system, block_m)
         return (block_m, bn) if bn is not None else None
     bm = auto_block_m(system)
     if bm is not None:
         return bm, None
-    cap = _lane_cap(system)
     best = None
-    for bm in _BLOCK_M_CANDIDATES:
-        if cap is not None and bm > max(cap, _BLOCK_M_CANDIDATES[-1]):
-            continue
-        bn = auto_block_n(system, bm, prefetch=prefetch)
+    for bm in _lane_tiles(system):
+        bn = auto_block_n(system, bm)
         if bn is None:
             continue
         if best is None:
             best = (bm, bn)  # largest two-call-capable lane tile
-        fbn = _fused_block_n(system, bm, prefetch=prefetch)
-        if fbn is not None and _pick_fused(system, bm, fbn,
-                                           prefetch=prefetch):
+        fbn = _fused_block_n(system, bm)
+        if fbn is not None and _pick_fused(system, bm, fbn):
             # The modelled HBM bytes are lane-tile independent, so a
             # fused-capable pair at a SMALLER tile still moves ~half the
             # bytes of the two-call pair at a larger one — prefer it (the
@@ -269,26 +219,24 @@ def supports(system: BandedSystem, *, block_m: int | None = None,
                        "use backend='reference'")
     tuned = auto_tune(system, block_m=block_m, block_n=block_n)
     if tuned is None:
-        n_rhs, n_lhs = _vmem_counts(system)
-        itemsize = jnp.dtype(system.dtype).itemsize
         bm = block_m if block_m is not None else _BLOCK_M_CANDIDATES[-1]
+        spec = _spec_for(system, streamed=block_n is not None)
+        vmem, smem = _kcommon.spec_working_set(
+            spec, system.n, bm, block_n, itemsize=_itemsize(system))
         if block_n is not None:
             # the failing candidate was an explicit streamed request —
             # report the streamed chunk working set, not the resident one
-            s_rhs, s_lhs, s_carry = _streamed_counts(system)
-            ws = _kcommon.streamed_vmem_working_set(
-                block_n, bm, s_rhs, s_lhs, s_carry, itemsize=itemsize)
-            desc = (f"streamed working set {ws / 2**20:.1f} MiB at "
-                    f"block_n={block_n}")
+            desc = (f"streamed working set {vmem / 2**20:.1f} MiB "
+                    f"(SMEM {smem / 2**10:.0f} KiB) at block_n={block_n}")
             extra = ""
         else:
-            ws = _kcommon.vmem_working_set(system.n, bm, n_rhs, n_lhs,
-                                           itemsize=itemsize)
-            desc = f"working set {ws / 2**20:.1f} MiB"
+            desc = (f"working set {vmem / 2**20:.1f} MiB "
+                    f"(SMEM {smem / 2**10:.0f} KiB)")
             extra = "; no streamed (block_m, block_n) pair fits either"
-        return False, (f"{desc} exceeds VMEM budget "
-                       f"({_kcommon.VMEM_BUDGET_BYTES / 2**20:.0f} "
-                       f"MiB){extra}")
+        return False, (f"{desc} exceeds the budget "
+                       f"(VMEM {_kcommon.VMEM_BUDGET_BYTES / 2**20:.0f} MiB, "
+                       f"SMEM {_kcommon.SMEM_BUDGET_BYTES / 2**10:.0f} "
+                       f"KiB){extra}")
     bm, bn = tuned
     if bn is None:
         return True, f"block_m={bm}"
@@ -321,9 +269,9 @@ def solve_stored(bandwidth: int, mode: str, periodic: bool, stored,
     ``block_n=None`` dispatches the VMEM-resident kernels; an integer
     selects the HBM-streamed kernels — the two-call split-N pair, or the
     fused single-call sweep when ``fused=True``.  ``storage_dtype``
-    down-casts the stored factor + streamed RHS in HBM (carries stay at
-    the fp32-promoted compute dtype); ``prefetch`` requests
-    double-buffered chunk DMA from the streamed builders."""
+    down-casts the streamed RHS (and per-lane diagonals) in HBM (the
+    shared factor and carries stay at the fp32-promoted compute dtype);
+    ``prefetch`` marks the streamed kernels' lane-tile axis parallel."""
     squeeze = rhs.ndim == 1
     if squeeze:
         rhs = rhs[:, None]
@@ -351,9 +299,9 @@ def solve_stored(bandwidth: int, mode: str, periodic: bool, stored,
         elif periodic:
             pf = stored
             y = _kops.penta_constant(pf.factor, rhs, uniform=uniform, **kw)
-            # rank-4 Woodbury corner correction (4 x M dots)
-            w = pf.Minv @ _penta._vty(pf.vcoef, y)
-            x = y - jnp.tensordot(pf.Z, w, axes=([1], [0]))
+            # rank-4 Woodbury corner correction (4 x M dots), shared with
+            # the reference sweeps
+            x = _penta.periodic_corner_correction(pf, y)
         else:
             x = _kops.penta_constant(stored, rhs, uniform=uniform, **kw)
     return x[:, 0] if squeeze else x
@@ -453,8 +401,7 @@ def _pure_build(system: BandedSystem, *, block_m: int | None = None,
     # dtype NAME goes into SolveMeta.options, ops re-canonicalises it)
     sdt = _kcommon.canonical_storage_dtype(storage_dtype)
     sdt_name = None if sdt is None else sdt.name
-    tuned = auto_tune(system, block_m=block_m, block_n=block_n,
-                      prefetch=prefetch)
+    tuned = auto_tune(system, block_m=block_m, block_n=block_n)
     if tuned is None:
         _, why = supports(system, block_m=block_m, block_n=block_n)
         raise NotImplementedError(
@@ -464,13 +411,12 @@ def _pure_build(system: BandedSystem, *, block_m: int | None = None,
         # explicit fused request: fused kernels are streamed by
         # construction, so force the streamed family even when the
         # resident kernel would have fit
-        bn = auto_block_n(system, bm, prefetch=prefetch)
+        bn = auto_block_n(system, bm)
         if bn is None:
             raise NotImplementedError(
                 f"pallas backend cannot stream {system.describe()} "
                 f"(fused=True requested) at block_m={bm}")
-    fused = resolve_fused(system, bm, bn, fused=fused, prefetch=prefetch,
-                          storage_dtype=sdt)
+    fused = resolve_fused(system, bm, bn, fused=fused, storage_dtype=sdt)
     return (build_stored(system),
             {"block_m": bm, "block_n": bn, "unroll": unroll,
              "interpret": interpret, "fused": fused,

@@ -40,8 +40,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.kernels.common import (canonical_storage_dtype, pad_lanes,
-                                  shard_lanes)
+from repro.kernels.common import (canonical_storage_dtype, default_interpret,
+                                  pad_lanes, shard_lanes)
 
 from .reference import (build_stored, solve_stored, transpose_solve_stored)
 from .registry import register_backend, register_pure_backend
@@ -87,16 +87,14 @@ def local_system(system: BandedSystem, n_shards: int) -> BandedSystem:
 
 def local_tune(system: BandedSystem, n_shards: int, *,
                block_m: int | None = None,
-               block_n: int | None = None,
-               prefetch: bool = False) -> tuple | None:
+               block_n: int | None = None) -> tuple | None:
     """Per-device ``(block_m, block_n)`` — the single-device 2-D auto-tune
     (``pallas.auto_tune``) run on the LOCAL system view.  ``None`` when no
     kernel configuration fits, or no kernel family serves the mode at all
     (the caller falls back to reference sweeps per shard)."""
     from . import pallas as _pallas
     return _pallas.auto_tune(local_system(system, n_shards),
-                             block_m=block_m, block_n=block_n,
-                             prefetch=prefetch)
+                             block_m=block_m, block_n=block_n)
 
 
 def sharded_solve_stored(bandwidth: int, mode: str, periodic: bool, n: int,
@@ -119,10 +117,17 @@ def sharded_solve_stored(bandwidth: int, mode: str, periodic: bool, n: int,
     M axis to the mesh size uses the kernels' shared ``pad_lanes``:
     per-system MAIN-diagonal copies identity-pad (b = 1) so the dead
     padded lanes factor as identity solves instead of 1/0."""
-    from jax.experimental.shard_map import shard_map
-
+    # The varying-axes check stays on, except around the Pallas
+    # interpreter: JAX 0.9's interpreter runs a kernel body traced without
+    # the operands' varying axes, so its grid loop fails the check.  The
+    # compiled kernels type their outputs with the operands' axes
+    # (``engine._out_struct``) and run checked.
+    check_vma = True
     if kernels == "pallas":
         from . import pallas as _pallas
+        if interpret is None:
+            interpret = default_interpret()
+        check_vma = not interpret
 
         def local_solve(st, r):
             return _pallas.tuned_solve_stored(
@@ -147,13 +152,14 @@ def sharded_solve_stored(bandwidth: int, mode: str, periodic: bool, n: int,
         main = diagonal_names[bandwidth // 2]
         padded = {k: pad_lanes(v, n_shards, identity=(k == main))[0]
                   for k, v in stored.items()}
-        fn = shard_map(local_solve, mesh=mesh, in_specs=(spec, spec),
-                       out_specs=spec, check_rep=False)
+        fn = jax.shard_map(local_solve, mesh=mesh, in_specs=(spec, spec),
+                           out_specs=spec, check_vma=check_vma)
         x = fn(padded, pad_lanes(rhs, n_shards)[0])
     else:
         # replicated: closed over, one copy per device
-        fn = shard_map(lambda r: local_solve(stored, r), mesh=mesh,
-                       in_specs=(spec,), out_specs=spec, check_rep=False)
+        fn = jax.shard_map(lambda r: local_solve(stored, r), mesh=mesh,
+                           in_specs=(spec,), out_specs=spec,
+                           check_vma=check_vma)
         x = fn(pad_lanes(rhs, n_shards)[0])
 
     x = x[:, :m]
@@ -183,7 +189,7 @@ def _pure_build(system: BandedSystem, *, mesh: Mesh | None = None,
     tuned = None
     if kernels != "reference":
         tuned = local_tune(system, n_shards, block_m=block_m,
-                           block_n=block_n, prefetch=prefetch)
+                           block_n=block_n)
         if tuned is None and kernels == "pallas":
             from . import pallas as _pallas
             _, why = _pallas.supports(local_system(system, n_shards),
@@ -201,8 +207,7 @@ def _pure_build(system: BandedSystem, *, mesh: Mesh | None = None,
         # per-device fused resolution: same traffic-model argmin as the
         # single-device tuner, sized against the LOCAL system view
         fused = _pallas.resolve_fused(local_system(system, n_shards), bm, bn,
-                                      fused=fused, prefetch=prefetch,
-                                      storage_dtype=sdt)
+                                      fused=fused, storage_dtype=sdt)
         opts.update(kernels="pallas", shard_build="pallas", block_m=bm,
                     block_n=bn, interpret=interpret, fused=fused,
                     storage_dtype=None if sdt is None else sdt.name,

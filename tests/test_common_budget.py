@@ -2,57 +2,73 @@
 (``repro.kernels.common``) — exactly-at-budget must pass, one byte over
 must raise, and aligned padding must be an identity (no copy)."""
 
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from repro.kernels.common import (VMEM_BUDGET_BYTES, check_vmem,
-                                  check_vmem_streamed, pad_lanes,
-                                  pad_sweep, pad_to_multiple, shard_lanes,
-                                  streamed_vmem_working_set,
-                                  vmem_working_set)
+from repro.kernels.common import (SMEM_BUDGET_BYTES, VMEM_BUDGET_BYTES,
+                                  check_fits, pad_lanes, pad_sweep,
+                                  pad_to_multiple, shard_lanes,
+                                  smem_working_set, vmem_working_set)
 
 
 # ---------------------------------------------------------------------------
 # Budget boundaries (itemsize=1 makes the working set exactly countable)
 # ---------------------------------------------------------------------------
 
+def _spec(blocks, lhs_rows=0, carry=0, scratch=0, streamed=False):
+    """A duck-typed spec with the given counts (check_fits reads only
+    vmem_counts / sweep_scratch / name / fused)."""
+    return SimpleNamespace(vmem_counts=lambda: (blocks, lhs_rows, carry),
+                           sweep_scratch=lambda: scratch, name="probe",
+                           fused=False, streamed=streamed)
+
+
 def test_resident_budget_exactly_at():
-    # ws = (1 * 1 * (B - 4) + 4 * 1) * 1 == VMEM_BUDGET_BYTES
-    block_m = VMEM_BUDGET_BYTES - 4
-    assert vmem_working_set(1, block_m, 1, 4, itemsize=1) == \
-        VMEM_BUDGET_BYTES
-    check_vmem(1, block_m, n_rhs_blocks=1, n_lhs_vecs=4, itemsize=1)
+    # ws = 2 * 1 * (B / 2) * 1 == VMEM_BUDGET_BYTES: blocks count twice
+    n = VMEM_BUDGET_BYTES // 2
+    assert vmem_working_set(n, 1, 1, itemsize=1) == VMEM_BUDGET_BYTES
+    check_fits(_spec(1), n, 1, itemsize=1)
 
 
 def test_resident_budget_one_byte_over():
-    block_m = VMEM_BUDGET_BYTES - 4
+    n = VMEM_BUDGET_BYTES // 2
     with pytest.raises(ValueError, match="exceeds VMEM budget"):
-        check_vmem(1, block_m, n_rhs_blocks=1, n_lhs_vecs=5, itemsize=1)
+        check_fits(_spec(1, carry=1), n, 1, itemsize=1)
 
 
 def test_streamed_budget_exactly_at():
-    # ws = (1 * 1 * (B - 7) + 3 * 1 + 4 * (B - 7)) ... keep it simple:
-    # block_n = block_m = 1 -> ws = n_rhs + n_lhs + n_carry
-    n_rhs = VMEM_BUDGET_BYTES - 7
-    assert streamed_vmem_working_set(1, 1, n_rhs, 3, 4, itemsize=1) == \
-        VMEM_BUDGET_BYTES
-    check_vmem_streamed(1, 1, n_rhs_blocks=n_rhs, n_lhs_vecs=3, n_carry=4,
-                        itemsize=1)
+    # block_n = block_m = 1 -> ws = 2 * n_blocks + n_carry
+    n_blocks = (VMEM_BUDGET_BYTES - 4) // 2
+    assert vmem_working_set(64, 1, n_blocks, block_n=1, n_carry=4,
+                            itemsize=1) == VMEM_BUDGET_BYTES
+    check_fits(_spec(n_blocks, carry=4, streamed=True), 64, 1, 1,
+               itemsize=1)
 
 
 def test_streamed_budget_one_byte_over():
-    n_rhs = VMEM_BUDGET_BYTES - 7
+    n_blocks = (VMEM_BUDGET_BYTES - 4) // 2
     with pytest.raises(ValueError, match="exceeds VMEM"):
-        check_vmem_streamed(1, 1, n_rhs_blocks=n_rhs, n_lhs_vecs=3,
-                            n_carry=5, itemsize=1)
+        check_fits(_spec(n_blocks, carry=5, streamed=True), 64, 1, 1,
+                   itemsize=1)
 
 
 def test_budget_scales_with_itemsize():
     # the float64 working set is twice the float32 one — the checks must
     # use the caller's itemsize, not assume 4 bytes
-    assert vmem_working_set(8, 16, 2, 3, itemsize=8) == \
-        2 * vmem_working_set(8, 16, 2, 3, itemsize=4)
+    assert vmem_working_set(8, 16, 2, n_carry=3, itemsize=8) == \
+        2 * vmem_working_set(8, 16, 2, n_carry=3, itemsize=4)
+
+
+def test_smem_budget_bounds_the_shared_lhs():
+    # rows pad to the 8-row tile and count twice: a 5-row LHS at N=16384
+    # is exactly the 1 MiB budget, one more column is over
+    assert smem_working_set(5, 16384) == SMEM_BUDGET_BYTES
+    check_fits(_spec(0, lhs_rows=5), 16384, 128, itemsize=4)
+    with pytest.raises(ValueError, match="exceeds SMEM"):
+        check_fits(_spec(0, lhs_rows=5), 16385, 128, itemsize=4)
 
 
 # ---------------------------------------------------------------------------

@@ -255,13 +255,19 @@ def test_traffic_derivation_matches_paper_numbers():
 
 
 def test_vmem_counts_are_spec_derived():
-    """The budget checks reason from the spec's stream structure."""
+    """The budget checks reason from the spec's stream structure:
+    (pipelined blocks, SMEM LHS rows, VMEM carry rows) + full-N scratch."""
     assert REGISTRY["thomas_constant"].vmem_counts() == (2, 3, 1)
     assert REGISTRY["penta_constant"].vmem_counts() == (2, 5, 2)
     assert REGISTRY["penta_uniform"].vmem_counts() == (2, 4, 2)
-    # batch fwd kernels: diagonals + rhs in, intermediate + coefs out
-    assert REGISTRY["thomas_batch"].vmem_counts() == (6, 0, 2)
-    assert REGISTRY["penta_batch"].vmem_counts() == (9, 0, 6)
+    # resident batch: diagonals + rhs in, x out; coefs in full-N scratch
+    assert REGISTRY["thomas_batch"].vmem_counts() == (5, 0, 2)
+    assert REGISTRY["thomas_batch"].sweep_scratch() == 1
+    assert REGISTRY["penta_batch"].vmem_counts() == (7, 0, 6)
+    assert REGISTRY["penta_batch"].sweep_scratch() == 2
+    # streamed batch fwd: diagonals + rhs in, intermediate + coefs out
+    assert REGISTRY["thomas_batch_streamed"].vmem_counts() == (6, 0, 2)
+    assert REGISTRY["penta_batch_streamed"].vmem_counts() == (9, 0, 6)
     # transposed shares the forward's working set
     assert REGISTRY["thomas_constant_t"].vmem_counts() \
         == REGISTRY["thomas_constant"].vmem_counts()

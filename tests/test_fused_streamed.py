@@ -36,7 +36,7 @@ from repro.kernels.engine import REGISTRY, SweepSpec
 from repro.solver import BandedSystem, factorize, solve
 from repro.solver import pallas as solver_pallas
 
-#: resident tridiag/penta working sets exceed the 12 MiB budget here, so
+#: resident tridiag/penta working sets exceed the 16 MiB budget here, so
 #: the auto tuner must stream — and the fused full-N scratch still fits
 #: at block_m=128 (16384 * 128 * 4 B = 8 MiB).
 HUGE_N = 16384
@@ -185,7 +185,8 @@ def test_grad_parity_through_fused():
 def test_auto_picks_fused_at_huge_n_shared():
     """At HUGE_N the resident path is over budget at every block_m; the
     auto tuner must land on the fused streamed point (block_m=128 is the
-    only tile whose full-N scratch fits 12 MiB)."""
+    only tile whose full-N scratch fits 16 MiB beside its double-buffered
+    chunks)."""
     for bandwidth in (3, 5):
         system = _shared_system(bandwidth, HUGE_N)
         fact = factorize(system, backend="auto")
@@ -222,9 +223,9 @@ def test_explicit_fused_forces_streaming():
 
 
 def test_prefetch_knob_recorded_and_harmless():
-    """prefetch=True (the default through factorize) doubles the modelled
-    chunk residency for double-buffered DMA; in interpret mode it must
-    not change the answer, only the recorded plan."""
+    """prefetch=True (the default through factorize) marks the lane axis
+    parallel on the chip; in interpret mode it must not change the
+    answer, only the recorded plan."""
     system = _shared_system(3, 100)
     rng = np.random.default_rng(2)
     rhs = jnp.asarray(rng.normal(size=(100, 24)).astype(np.float32))
@@ -295,6 +296,6 @@ def test_fused_vmem_model_gates_the_tuner():
     system = _shared_system(3, HUGE_N)
     assert solver_pallas._fused_fits(system, 128, 1024)
     assert not solver_pallas._fused_fits(system, 1024, 512)
-    ws = kcommon.fused_vmem_working_set(HUGE_N, 1024, 128, 2, 1, 1, 1,
-                                        itemsize=4, compute_itemsize=4)
+    ws, _ = kcommon.spec_working_set(
+        REGISTRY["thomas_constant_streamed_fused"], HUGE_N, 128, 1024)
     assert ws <= kcommon.VMEM_BUDGET_BYTES

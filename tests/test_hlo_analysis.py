@@ -22,9 +22,9 @@ def test_trip_count_scales_loop_collectives():
         y, _ = jax.lax.scan(body, x, None, length=7)
         return y
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    fn = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P())
+    fn = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+                       check_vma=True)
     compiled = jax.jit(fn).lower(
         jax.ShapeDtypeStruct((64, 32), jnp.float32)).compile()
     txt = compiled.as_text()

@@ -5,6 +5,8 @@ subprocess with XLA_FLAGS=--xla_force_host_platform_device_count=8 so the
 main test process keeps its single-device view.
 """
 
+import os
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -23,14 +25,16 @@ from repro.runtime import (
     bubble_fraction,
 )
 
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
+
 
 def _run_subprocess(code: str):
     env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=8",
            "PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin",
-           "HOME": "/root"}
+           "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu"}
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, timeout=600, env=env,
-                       cwd="/root/repo")
+                       cwd=REPO_ROOT)
     assert r.returncode == 0, r.stderr[-3000:]
     return r.stdout
 
@@ -149,7 +153,6 @@ def test_sharded_solver_multidevice():
     device, systems sharded, no result drift."""
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core import thomas_factor, thomas_solve
         mesh = jax.make_mesh((8,), ("batch",))
@@ -160,9 +163,9 @@ def test_sharded_solver_multidevice():
         b = (np.abs(a) + np.abs(c) + 2.5).astype(np.float32)
         d = rng.normal(size=(n, m)).astype(np.float32)
         f = thomas_factor(jnp.asarray(a), jnp.asarray(b), jnp.asarray(c))
-        solve = shard_map(lambda fac, dd: thomas_solve(fac, dd),
-                          mesh=mesh, in_specs=(P(), P(None, "batch")),
-                          out_specs=P(None, "batch"))
+        solve = jax.shard_map(lambda fac, dd: thomas_solve(fac, dd),
+                              mesh=mesh, in_specs=(P(), P(None, "batch")),
+                              out_specs=P(None, "batch"), check_vma=True)
         got = jax.jit(solve)(f, jnp.asarray(d))
         want = thomas_solve(f, jnp.asarray(d))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),

@@ -122,7 +122,7 @@ def test_auto_prefers_pallas_when_it_fits():
 
 def test_auto_falls_back_to_reference_when_vmem_would_trip(monkeypatch):
     """backend='auto' must degrade to reference instead of raising when
-    check_vmem would reject even the smallest block_m."""
+    check_fits would reject even the smallest block_m."""
     rng = np.random.default_rng(3)
     coeffs = _tridiag_coeffs(rng, N, uniform=False)
     system = _system(3, coeffs, False, "constant", None)
@@ -151,8 +151,9 @@ def test_block_m_autotunes_against_vmem_budget(monkeypatch):
     system = BandedSystem.tridiag(*coeffs, n=256)
     # plenty of budget -> largest candidate
     assert solver_pallas.auto_block_m(system) == 1024
-    # (2*256*bm + 3*256)*4 bytes: 600 kB fits bm=256, not bm=512
-    monkeypatch.setattr(kcommon, "VMEM_BUDGET_BYTES", 600_000)
+    # 2 double-buffered (256, bm) fp32 blocks + one carry row:
+    # (2*2*256*bm + bm)*4 bytes — 1.1 MB fits bm=256, not bm=512
+    monkeypatch.setattr(kcommon, "VMEM_BUDGET_BYTES", 1_100_000)
     assert solver_pallas.auto_block_m(system) == 256
     p = plan(system, backend="pallas")
     assert p.impl.block_m == 256

@@ -279,7 +279,7 @@ def test_auto_tune_streams_explicit_oversize_block_m():
     """An (N, block_m) pair whose resident working set exceeds the budget
     resolves to a streamed pair instead of being rejected."""
     system = BandedSystem.tridiag(-0.4, 1.8, -0.4, n=8192)
-    ws = kcommon.vmem_working_set(8192, 1024, 2, 3, itemsize=4)
+    ws = kcommon.vmem_working_set(8192, 1024, 2, n_carry=1, itemsize=4)
     assert ws > kcommon.VMEM_BUDGET_BYTES
     bm, bn = solver_pallas.auto_tune(system, block_m=1024)
     assert bm == 1024 and bn is not None
