@@ -1,0 +1,12 @@
+"""Device time per call, in ms, of the operations the program issues in
+its ``repro.corner_correction`` scope (the periodic Sherman-Morrison or
+Woodbury correction and the reductions that feed it), on the chip that
+spent most on them; each operation's layer is read from its ``tf_op`` in
+the trace (``scopes.py``).  None where the trace was reduced without
+layers, or no operation carries the scope."""
+
+import scopes
+
+
+def read(run):
+    return scopes.layer_ms(run, scopes.CORNER_CORRECTION)

@@ -71,6 +71,7 @@ class CallRecord:
     scratch_shapes: tuple # MemoryRef per scratch operand
     arg_ids: tuple        # id() of each operand buffer (scalar-param dedupe)
     arg_shapes: tuple
+    name: str | None = None   # the ``name=`` the builder gave the call
 
     def grid_points(self) -> list:
         return list(itertools.product(*(range(g) for g in self.grid)))
@@ -94,7 +95,7 @@ def capture_pallas_calls():
     real = pl.pallas_call
 
     def fake_pallas_call(kernel, *, grid, in_specs, out_specs, out_shape,
-                         scratch_shapes=(), **_kwargs):
+                         scratch_shapes=(), name=None, **_kwargs):
         multi = isinstance(out_shape, (list, tuple))
         outs = tuple(out_shape) if multi else (out_shape,)
         ospecs = (tuple(out_specs) if isinstance(out_specs, (list, tuple))
@@ -106,7 +107,8 @@ def capture_pallas_calls():
                 in_specs=tuple(in_specs), out_specs=ospecs, out_shapes=outs,
                 scratch_shapes=tuple(scratch_shapes),
                 arg_ids=tuple(id(a) for a in args),
-                arg_shapes=tuple(tuple(a.shape) for a in args)))
+                arg_shapes=tuple(tuple(a.shape) for a in args),
+                name=name))
             res = [jnp.zeros(o.shape, o.dtype) for o in outs]
             return res if multi else res[0]
 

@@ -31,6 +31,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
+
 from .recurrence import _align, linear_recurrence2
 
 
@@ -200,8 +202,10 @@ def periodic_corner_correction(pf: PeriodicPentaFactor,
     """Rank-4 Woodbury corner step on y = A'^{-1} rhs:
     x = y - Z (I + V^T Z)^{-1} V^T y.  Shared by the reference solve below
     and the ``pallas`` backend's kernel-produced y."""
-    w = jnp.matmul(pf.Minv, _vty(pf.vcoef, y), precision=_HIGHEST)
-    return y - jnp.tensordot(pf.Z, w, axes=([1], [0]), precision=_HIGHEST)
+    with jax.named_scope(scopes.CORNER_CORRECTION):
+        w = jnp.matmul(pf.Minv, _vty(pf.vcoef, y), precision=_HIGHEST)
+        return y - jnp.tensordot(pf.Z, w, axes=([1], [0]),
+                                 precision=_HIGHEST)
 
 
 def periodic_penta_solve(pf: PeriodicPentaFactor, rhs: jax.Array, *,
@@ -233,9 +237,11 @@ def periodic_corner_correction_t(pf: PeriodicPentaFactor,
     by the reference transposed solve below and the ``pallas`` backend's
     kernel-produced y — ONE home for the corner algebra.
     """
-    uty = jnp.stack([y[0], y[1], y[-2], y[-1]], axis=0)            # U^T y
-    h = jnp.matmul(pf.Minv.T, uty, precision=_HIGHEST)
-    return y - jnp.tensordot(pf.Zt, h, axes=([1], [0]), precision=_HIGHEST)
+    with jax.named_scope(scopes.CORNER_CORRECTION):
+        uty = jnp.stack([y[0], y[1], y[-2], y[-1]], axis=0)        # U^T y
+        h = jnp.matmul(pf.Minv.T, uty, precision=_HIGHEST)
+        return y - jnp.tensordot(pf.Zt, h, axes=([1], [0]),
+                                 precision=_HIGHEST)
 
 
 def periodic_penta_solve_t(pf: PeriodicPentaFactor, g: jax.Array, *,

@@ -28,6 +28,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
+
 from .recurrence import _align, linear_recurrence
 
 
@@ -183,14 +185,24 @@ def periodic_thomas_factor(a: jax.Array, b: jax.Array, c: jax.Array, *,
     )
 
 
+def periodic_corner_correction(pf: PeriodicTridiagFactor,
+                               y: jax.Array) -> jax.Array:
+    """Sherman-Morrison corner step on y = A'^{-1} d (paper Eq. 15):
+        x = y - (v.y / (1 + v.z)) z.
+    Shared by the reference solve below and the ``pallas`` backend's
+    kernel-produced y."""
+    with jax.named_scope(scopes.CORNER_CORRECTION):
+        v_dot_y = y[0] + pf.v_last * y[-1]          # (M,) for batched y
+        corr = v_dot_y * pf.inv_denom_sm
+        z = _align(pf.z, y) if pf.z.ndim < y.ndim else pf.z
+        return y - corr * z
+
+
 def periodic_thomas_solve(pf: PeriodicTridiagFactor, d: jax.Array, *,
                           method: str = "scan", unroll: int = 1) -> jax.Array:
     """x = y - (v.y / (1 + v.z)) z  with  y = A'^{-1} d  (paper Eq. 15)."""
     y = thomas_solve(pf.factor, d, method=method, unroll=unroll)
-    v_dot_y = y[0] + pf.v_last * y[-1]          # (M,) for batched d
-    corr = v_dot_y * pf.inv_denom_sm
-    z = _align(pf.z, y) if pf.z.ndim < y.ndim else pf.z
-    return y - corr * z
+    return periodic_corner_correction(pf, y)
 
 
 def periodic_corner_correction_t(pf: PeriodicTridiagFactor,
@@ -209,12 +221,13 @@ def periodic_corner_correction_t(pf: PeriodicTridiagFactor,
     the factor-convention algebra.
     """
     f = pf.factor
-    gamma = -0.5 / f.inv_denom[0]
-    c_last = f.c_hat[-1] / f.inv_denom[-1]
-    u_dot_y = gamma * y[0] + c_last * y[-1]
-    corr = u_dot_y * pf.inv_denom_sm
-    zt = _align(pf.zt, y) if pf.zt.ndim < y.ndim else pf.zt
-    return y - corr * zt
+    with jax.named_scope(scopes.CORNER_CORRECTION):
+        gamma = -0.5 / f.inv_denom[0]
+        c_last = f.c_hat[-1] / f.inv_denom[-1]
+        u_dot_y = gamma * y[0] + c_last * y[-1]
+        corr = u_dot_y * pf.inv_denom_sm
+        zt = _align(pf.zt, y) if pf.zt.ndim < y.ndim else pf.zt
+        return y - corr * zt
 
 
 def periodic_thomas_solve_t(pf: PeriodicTridiagFactor, g: jax.Array, *,

@@ -28,7 +28,10 @@ This module makes that observation executable (DESIGN.md §2.2):
     builders.  They own the grid layout, the ``chunk_spec`` index maps,
     the VMEM carry scratch, the ``reset_carry`` zero-init (which makes the
     boundary rows fall out of the general recurrence — no first/last-row
-    special cases anywhere), and emit the ``pl.pallas_call`` pair.
+    special cases anywhere), and emit the ``pl.pallas_call`` pair, each
+    call named by its spec (``name=spec.name``; the two calls of a
+    streamed pair add ``_fwd`` / ``_bwd``), so traces and Mosaic dumps
+    name the kernel that ran.
   * ``REGISTRY`` — every variant the engine generates, by name.  Traffic
     (``SweepSpec.traffic_bytes``) and VMEM accounting
     (``SweepSpec.vmem_counts``) are derived from the spec, so a new
@@ -907,6 +910,7 @@ def shared_solver(spec: SweepSpec):
                 out_shape=_out_struct((n, m), cdt, *args),
                 scratch_shapes=[pltpu.VMEM((spec.order, block_m), cdt)],
                 interpret=interpret,
+                name=spec.name,
                 **_compiler_params(interpret, streamed=False),
             )(*args)
         return solver
@@ -940,6 +944,7 @@ def shared_solver(spec: SweepSpec):
                 scratch_shapes=[pltpu.VMEM((n, block_m), cdt),
                                 pltpu.VMEM((spec.order, block_m), cdt)],
                 interpret=interpret,
+                name=spec.name,
                 **_compiler_params(interpret, streamed=True,
                                    prefetch=prefetch),
             )(*args)
@@ -958,7 +963,7 @@ def shared_solver(spec: SweepSpec):
         carry = [pltpu.VMEM((spec.order, block_m), cdt)]
         fwd, bwd = spec.passes()
 
-        def one_pass(pspec, reverse, operand):
+        def one_pass(pspec, reverse, operand, name):
             in_specs = [chunk_lhs_spec(spec.lhs_rows, block_n, num_n,
                                        reverse=reverse),
                         chunk_spec(block_n, block_m, num_n, reverse=reverse)]
@@ -977,12 +982,13 @@ def shared_solver(spec: SweepSpec):
                 out_shape=_out_struct((n, m), cdt, *args),
                 scratch_shapes=carry,
                 interpret=interpret,
+                name=name,
                 **_compiler_params(interpret, streamed=True,
                                    prefetch=prefetch),
             )(*args)
 
-        mid = one_pass(fwd, False, rhs)           # ascending: d_hat / g
-        return one_pass(bwd, True, mid)           # descending: x
+        mid = one_pass(fwd, False, rhs, f"{spec.name}_fwd")  # d_hat / g
+        return one_pass(bwd, True, mid, f"{spec.name}_bwd")  # x
     return solver
 
 
@@ -1127,6 +1133,7 @@ def batch_solver(spec: SweepSpec):
                                + [pltpu.VMEM((spec.carry_rows, block_m),
                                              cdt)],
                 interpret=interpret,
+                name=spec.name,
                 **_compiler_params(interpret, streamed=False),
             )(*args)
         return solver
@@ -1157,6 +1164,7 @@ def batch_solver(spec: SweepSpec):
                                + [pltpu.VMEM((spec.carry_rows, block_m),
                                              cdt)],
                 interpret=interpret,
+                name=spec.name,
                 **_compiler_params(interpret, streamed=True,
                                    prefetch=prefetch),
             )(*args)
@@ -1186,6 +1194,7 @@ def batch_solver(spec: SweepSpec):
             out_shape=[shape] * (1 + spec.n_coefs),
             scratch_shapes=[pltpu.VMEM((spec.carry_rows, block_m), cdt)],
             interpret=interpret,
+            name=f"{spec.name}_fwd",
             **params,
         )(*args)
         mid, coefs = outs[0], outs[1:]
@@ -1200,6 +1209,7 @@ def batch_solver(spec: SweepSpec):
             out_shape=shape,
             scratch_shapes=[pltpu.VMEM((spec.order, block_m), cdt)],
             interpret=interpret,
+            name=f"{spec.name}_bwd",
             **params,
         )(*coefs, mid)
     return solver
@@ -1283,6 +1293,7 @@ def recurrence_solver(spec: RecurrenceSpec):
                 scratch_shapes=[pltpu.VMEM((spec.order, block_m),
                                            args[-1].dtype)],
                 interpret=interpret,
+                name=spec.name,
                 **_compiler_params(interpret, streamed=False),
             )(*args)
         return solver
@@ -1304,6 +1315,7 @@ def recurrence_solver(spec: RecurrenceSpec):
             scratch_shapes=[pltpu.VMEM((spec.order, block_m),
                                        args[-1].dtype)],
             interpret=interpret,
+            name=spec.name,
             **_compiler_params(interpret, streamed=True),
         )(*args)
     return solver

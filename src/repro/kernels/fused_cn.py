@@ -32,6 +32,10 @@ from .common import VMEM_LIMIT_BYTES, default_interpret, lhs_spec, row, \
     store_row
 
 
+#: The kernel's name in traces and Mosaic dumps (``pallas_call(name=...)``).
+NAME = "fused_cn_tridiag"
+
+
 def fused_cn_tridiag_kernel(lhs_ref, z_ref, p_ref, c_ref, x_ref, *,
                             n: int, unroll: int):
     sl = p_ref[0, 0]
@@ -94,6 +98,7 @@ def fused_cn_tridiag_pallas(lhs, z, params, c, *, block_m: int = 128,
         out_specs=col,
         out_shape=jax.ShapeDtypeStruct((n, m), c.dtype),
         interpret=interpret,
+        name=NAME,
         **cn_compiler_params(interpret),
     )(lhs, z, params, c)
 

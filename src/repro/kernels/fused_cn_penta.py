@@ -30,6 +30,10 @@ from .fused_cn import cn_compiler_params
 EPS, BETA, INV_ALPHA, GAMMA, DELTA = range(5)
 
 
+#: The kernel's name in traces and Mosaic dumps (``pallas_call(name=...)``).
+NAME = "fused_cn_penta"
+
+
 def fused_cn_penta_kernel(lhs_ref, z_ref, minv_ref, p_ref, c_ref, x_ref, *,
                           n: int, unroll: int):
     w = [p_ref[0, i] for i in range(5)]                      # stencil
@@ -116,6 +120,7 @@ def fused_cn_penta_pallas(lhs, z, minv, params, c, *, block_m: int = 128,
         out_specs=col,
         out_shape=jax.ShapeDtypeStruct((n, m), c.dtype),
         interpret=interpret,
+        name=NAME,
         **cn_compiler_params(interpret),
     )(lhs, z, minv, params, c)
 

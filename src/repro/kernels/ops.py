@@ -10,6 +10,9 @@ These wrap the engine-generated ``pallas_call`` kernels
     sweep padding (streamed kernels pad N to the chunk multiple; batch
     operands identity-pad the main diagonal on BOTH axes because the
     fused factorisation divides in-kernel),
+  * the layer scopes of ``repro.scopes``: each dispatch runs in
+    ``repro.kernel.<spec name>``, its pads, casts and output slices in
+    ``repro.pad_cast`` nested inside it,
   * automatic ``interpret=True`` off-TPU (validation mode on CPU),
   * spec-derived VMEM-budget checks,
   * an optional ``shard_map`` distribution over the system/batch axis — the
@@ -24,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro import scopes
 from repro.core import (PentaFactor, PeriodicPentaFactor,
                         PeriodicTridiagFactor, TridiagFactor)
 from . import common as _common
@@ -33,8 +37,7 @@ from .common import (LANE, canonical_storage_dtype, check_fits,
 from .engine import (RecurrenceSpec, SweepSpec, batch_solver,
                      find_recurrence_spec, find_spec, recurrence_solver,
                      shared_solver)
-from .fused_cn import fused_cn_tridiag_pallas
-from .fused_cn_penta import fused_cn_penta_pallas
+from . import fused_cn, fused_cn_penta
 
 
 def _shift_down(v: jax.Array, k: int) -> jax.Array:
@@ -84,6 +87,55 @@ def _check_spec_vmem(spec: SweepSpec, n: int, block_m: int,
                itemsize=jnp.promote_types(dtype, jnp.float32).itemsize)
 
 
+def _pad_cast(operands, block_m: int, block_n: int | None, sdt,
+              idents) -> list:
+    """Lane pads, storage-dtype casts and (streamed) sweep pads of the
+    (N, M) operands; ``idents`` marks the main diagonals, padded with
+    ones."""
+    with jax.named_scope(scopes.PAD_CAST):
+        out = [pad_lanes(x, block_m, identity=i)[0]
+               for x, i in zip(operands, idents)]
+        if sdt is not None:
+            out = [x.astype(sdt) for x in out]
+        if block_n is not None:
+            out = [pad_sweep(x, block_n, axis=0, identity=i)[0]
+                   for x, i in zip(out, idents)]
+    return out
+
+
+def _unpad(x: jax.Array, n: int, m: int) -> jax.Array:
+    """The (n, m) solution out of the padded kernel output."""
+    with jax.named_scope(scopes.PAD_CAST):
+        return x[:n, :m]
+
+
+def _shared_dispatch(spec: SweepSpec, lhs: jax.Array, rhs: jax.Array, sdt,
+                     *, block_m: int, block_n: int | None, unroll: int,
+                     interpret: bool, prefetch: bool,
+                     eps=None) -> jax.Array:
+    """Pad, run and unpad one shared-LHS kernel ((rows, N) stacked LHS)."""
+    n, m = rhs.shape
+    (rhs_pad,) = _pad_cast([rhs], block_m, block_n, sdt, [False])
+    opts = dict(block_m=block_m, unroll=unroll, interpret=interpret, eps=eps)
+    if block_n is not None:
+        with jax.named_scope(scopes.PAD_CAST):
+            lhs, _ = pad_sweep(lhs, block_n, axis=1)
+        opts.update(block_n=block_n, prefetch=prefetch)
+    return _unpad(shared_solver(spec)(lhs, rhs_pad, **opts), n, m)
+
+
+def _batch_dispatch(spec: SweepSpec, operands, idents, sdt, *, block_m: int,
+                    block_n: int | None, unroll: int, interpret: bool,
+                    prefetch: bool) -> jax.Array:
+    """Pad, run and unpad one per-lane-LHS kernel (diagonals, then rhs)."""
+    n, m = operands[-1].shape
+    args = _pad_cast(operands, block_m, block_n, sdt, idents)
+    opts = dict(block_m=block_m, unroll=unroll, interpret=interpret)
+    if block_n is not None:
+        opts.update(block_n=block_n, prefetch=prefetch)
+    return _unpad(batch_solver(spec)(*args, **opts), n, m)
+
+
 def thomas_constant(f: TridiagFactor, d: jax.Array, *, block_m: int = 128,
                     block_n: int | None = None, unroll: int = 1,
                     interpret: bool | None = None,
@@ -108,20 +160,11 @@ def thomas_constant(f: TridiagFactor, d: jax.Array, *, block_m: int = 128,
     spec = find_spec(3, "constant", streamed=block_n is not None,
                      transposed=transposed, fused=fused)
     _check_spec_vmem(spec, n, block_m, block_n, d.dtype)
-    lhs = stack_tridiag_lhs(f, transposed=transposed)
-    d_pad, m = pad_lanes(d, block_m)
-    if sdt is not None:
-        d_pad = d_pad.astype(sdt)
-    if block_n is None:
-        x = shared_solver(spec)(lhs, d_pad, block_m=block_m, unroll=unroll,
-                                interpret=interpret)
-        return x[:, :m]
-    lhs, _ = pad_sweep(lhs, block_n, axis=1)
-    d_pad, _ = pad_sweep(d_pad, block_n, axis=0)
-    x = shared_solver(spec)(lhs, d_pad, block_m=block_m, block_n=block_n,
-                            unroll=unroll, interpret=interpret,
-                            prefetch=prefetch)
-    return x[:n, :m]
+    with jax.named_scope(scopes.kernel(spec.name)):
+        lhs = stack_tridiag_lhs(f, transposed=transposed)
+        return _shared_dispatch(spec, lhs, d, sdt, block_m=block_m,
+                                block_n=block_n, unroll=unroll,
+                                interpret=interpret, prefetch=prefetch)
 
 
 def thomas_batch(a, b, c, d, *, block_m: int = 128,
@@ -144,25 +187,16 @@ def thomas_batch(a, b, c, d, *, block_m: int = 128,
     marks the lane-tile grid axis parallel on hardware."""
     if interpret is None:
         interpret = default_interpret()
-    n, m = d.shape
+    n = d.shape[0]
     sdt = canonical_storage_dtype(storage_dtype)
     spec = find_spec(3, "batch", streamed=block_n is not None, fused=fused)
     _check_spec_vmem(spec, n, block_m, block_n, d.dtype)
     idents = (False, True, False, False)          # b is the main diagonal
-    args = [pad_lanes(x, block_m, identity=ident)[0]
-            for x, ident in zip((a, b, c, d), idents)]
-    if sdt is not None:
-        args = [x.astype(sdt) for x in args]
-    if block_n is None:
-        x = batch_solver(spec)(*args, block_m=block_m, unroll=unroll,
-                               interpret=interpret)
-        return x[:, :m]
-    args = [pad_sweep(x, block_n, axis=0, identity=ident)[0]
-            for x, ident in zip(args, idents)]
-    x = batch_solver(spec)(*args, block_m=block_m, block_n=block_n,
-                           unroll=unroll, interpret=interpret,
-                           prefetch=prefetch)
-    return x[:n, :m]
+    with jax.named_scope(scopes.kernel(spec.name)):
+        return _batch_dispatch(spec, (a, b, c, d), idents, sdt,
+                               block_m=block_m, block_n=block_n,
+                               unroll=unroll, interpret=interpret,
+                               prefetch=prefetch)
 
 
 def _uniform_eps_param(f: PentaFactor, dtype) -> jax.Array:
@@ -196,21 +230,13 @@ def penta_constant(f: PentaFactor, rhs: jax.Array, *, block_m: int = 128,
                      streamed=block_n is not None, transposed=transposed,
                      fused=fused)
     _check_spec_vmem(spec, n, block_m, block_n, rhs.dtype)
-    eps = _uniform_eps_param(f, rhs.dtype) if uniform else None
-    lhs = stack_penta_lhs(f, uniform=uniform, transposed=transposed)
-    rhs_pad, m = pad_lanes(rhs, block_m)
-    if sdt is not None:
-        rhs_pad = rhs_pad.astype(sdt)
-    if block_n is None:
-        x = shared_solver(spec)(lhs, rhs_pad, block_m=block_m,
-                                unroll=unroll, interpret=interpret, eps=eps)
-        return x[:, :m]
-    lhs, _ = pad_sweep(lhs, block_n, axis=1)
-    rhs_pad, _ = pad_sweep(rhs_pad, block_n, axis=0)
-    x = shared_solver(spec)(lhs, rhs_pad, block_m=block_m, block_n=block_n,
-                            unroll=unroll, interpret=interpret, eps=eps,
-                            prefetch=prefetch)
-    return x[:n, :m]
+    with jax.named_scope(scopes.kernel(spec.name)):
+        eps = _uniform_eps_param(f, rhs.dtype) if uniform else None
+        lhs = stack_penta_lhs(f, uniform=uniform, transposed=transposed)
+        return _shared_dispatch(spec, lhs, rhs, sdt, block_m=block_m,
+                                block_n=block_n, unroll=unroll,
+                                interpret=interpret, prefetch=prefetch,
+                                eps=eps)
 
 
 def penta_batch(a, b, c, d, e, rhs, *, block_m: int = 128,
@@ -227,25 +253,16 @@ def penta_batch(a, b, c, d, e, rhs, *, block_m: int = 128,
     compiled batch kernels refuse packed operands)."""
     if interpret is None:
         interpret = default_interpret()
-    n, m = rhs.shape
+    n = rhs.shape[0]
     sdt = canonical_storage_dtype(storage_dtype)
     spec = find_spec(5, "batch", streamed=block_n is not None, fused=fused)
     _check_spec_vmem(spec, n, block_m, block_n, rhs.dtype)
     idents = (False, False, True, False, False, False)  # c is the main diag
-    args = [pad_lanes(x, block_m, identity=ident)[0]
-            for x, ident in zip((a, b, c, d, e, rhs), idents)]
-    if sdt is not None:
-        args = [x.astype(sdt) for x in args]
-    if block_n is None:
-        x = batch_solver(spec)(*args, block_m=block_m, unroll=unroll,
-                               interpret=interpret)
-        return x[:, :m]
-    args = [pad_sweep(x, block_n, axis=0, identity=ident)[0]
-            for x, ident in zip(args, idents)]
-    x = batch_solver(spec)(*args, block_m=block_m, block_n=block_n,
-                           unroll=unroll, interpret=interpret,
-                           prefetch=prefetch)
-    return x[:n, :m]
+    with jax.named_scope(scopes.kernel(spec.name)):
+        return _batch_dispatch(spec, (a, b, c, d, e, rhs), idents, sdt,
+                               block_m=block_m, block_n=block_n,
+                               unroll=unroll, interpret=interpret,
+                               prefetch=prefetch)
 
 
 def recurrence(*operands, h0=None, reverse: bool = False,
@@ -278,33 +295,33 @@ def recurrence(*operands, h0=None, reverse: bool = False,
         raise ValueError(
             f"recurrence takes (p, q) or (s, t, u); got {order + 1} operands")
     n, m = q.shape
+    hs = None
     if h0 is not None:
         hs = (h0,) if order == 1 and not isinstance(h0, (tuple, list)) \
             else tuple(h0)
         if len(hs) != order:
             raise ValueError(f"h0 must carry {order} state(s), got {len(hs)}")
-        hs = tuple(jnp.broadcast_to(jnp.asarray(h), q.shape[1:]).astype(
-            q.dtype) for h in hs)
-        e0 = n - 1 if reverse else 0
-        fold = gates[0][e0] * hs[0]
-        if order == 2:
-            fold = fold + gates[1][e0] * hs[1]
-        q = q.at[e0].add(fold)
-        if order == 2 and n > 1:
-            e1 = n - 2 if reverse else 1
-            q = q.at[e1].add(gates[1][e1] * hs[0])
     spec = find_recurrence_spec(order, reverse=reverse,
                                 streamed=block_n is not None)
     _check_spec_vmem(spec, n, block_m, block_n, q.dtype)
-    args = [pad_lanes(x, block_m)[0] for x in (*gates, q)]
-    if block_n is None:
-        h = recurrence_solver(spec)(*args, block_m=block_m, unroll=unroll,
-                                    interpret=interpret)
-        return h[:, :m]
-    args = [pad_sweep(x, block_n, axis=0)[0] for x in args]
-    h = recurrence_solver(spec)(*args, block_m=block_m, block_n=block_n,
-                                unroll=unroll, interpret=interpret)
-    return h[:n, :m]
+    with jax.named_scope(scopes.kernel(spec.name)):
+        if hs is not None:
+            hs = tuple(jnp.broadcast_to(jnp.asarray(h), q.shape[1:]).astype(
+                q.dtype) for h in hs)
+            e0 = n - 1 if reverse else 0
+            fold = gates[0][e0] * hs[0]
+            if order == 2:
+                fold = fold + gates[1][e0] * hs[1]
+            q = q.at[e0].add(fold)
+            if order == 2 and n > 1:
+                e1 = n - 2 if reverse else 1
+                q = q.at[e1].add(gates[1][e1] * hs[0])
+        args = _pad_cast([*gates, q], block_m, block_n, None,
+                         [False] * (order + 1))
+        opts = dict(block_m=block_m, unroll=unroll, interpret=interpret)
+        if block_n is not None:
+            opts.update(block_n=block_n)
+        return _unpad(recurrence_solver(spec)(*args, **opts), n, m)
 
 
 def _check_cn_vmem(n: int, block_m: int, lhs_rows: int, dtype) -> None:
@@ -329,18 +346,20 @@ def fused_cn_step(pf: PeriodicTridiagFactor, sigma: float, c: jax.Array, *,
     """Fused periodic CN diffusion step (beyond-paper; see fused_cn.py)."""
     if interpret is None:
         interpret = default_interpret()
-    n = c.shape[0]
+    n, m = c.shape
     _check_cn_vmem(n, block_m, 3, c.dtype)
-    lhs = stack_tridiag_lhs(pf.factor)
-    z = pf.z.reshape(n, 1)
-    params = jnp.zeros((1, 8), c.dtype)
-    params = params.at[0, 0].set(sigma).at[0, 1].set(1 - 2 * sigma) \
-                   .at[0, 2].set(sigma).at[0, 3].set(pf.v_last) \
-                   .at[0, 4].set(pf.inv_denom_sm)
-    c_pad, m = pad_lanes(c, block_m)
-    x = fused_cn_tridiag_pallas(lhs, z, params, c_pad, block_m=block_m,
-                                unroll=unroll, interpret=interpret)
-    return x[:, :m]
+    with jax.named_scope(scopes.kernel(fused_cn.NAME)):
+        lhs = stack_tridiag_lhs(pf.factor)
+        z = pf.z.reshape(n, 1)
+        params = jnp.zeros((1, 8), c.dtype)
+        params = params.at[0, 0].set(sigma).at[0, 1].set(1 - 2 * sigma) \
+                       .at[0, 2].set(sigma).at[0, 3].set(pf.v_last) \
+                       .at[0, 4].set(pf.inv_denom_sm)
+        (c_pad,) = _pad_cast([c], block_m, None, None, [False])
+        x = fused_cn.fused_cn_tridiag_pallas(lhs, z, params, c_pad,
+                                             block_m=block_m, unroll=unroll,
+                                             interpret=interpret)
+        return _unpad(x, n, m)
 
 
 def fused_cn_penta_step(pf: PeriodicPentaFactor, sigma: float, c: jax.Array,
@@ -350,20 +369,21 @@ def fused_cn_penta_step(pf: PeriodicPentaFactor, sigma: float, c: jax.Array,
     see fused_cn_penta.py). c: (N, M) -> (N, M)."""
     if interpret is None:
         interpret = default_interpret()
-    n = c.shape[0]
+    n, m = c.shape
     _check_cn_vmem(n, block_m, 5, c.dtype)
-    lhs = stack_penta_lhs(pf.factor)
-    params = jnp.zeros((1, 16), c.dtype)
-    stencil = [-sigma, 4 * sigma, 1 - 6 * sigma, 4 * sigma, -sigma]
-    for i, v in enumerate(stencil):
-        params = params.at[0, i].set(v)
-    for i in range(6):
-        params = params.at[0, 5 + i].set(pf.vcoef[i])
-    c_pad, m = pad_lanes(c, block_m)
-    x = fused_cn_penta_pallas(lhs, pf.Z, pf.Minv, params, c_pad,
-                              block_m=block_m, unroll=unroll,
-                              interpret=interpret)
-    return x[:, :m]
+    with jax.named_scope(scopes.kernel(fused_cn_penta.NAME)):
+        lhs = stack_penta_lhs(pf.factor)
+        params = jnp.zeros((1, 16), c.dtype)
+        stencil = [-sigma, 4 * sigma, 1 - 6 * sigma, 4 * sigma, -sigma]
+        for i, v in enumerate(stencil):
+            params = params.at[0, i].set(v)
+        for i in range(6):
+            params = params.at[0, 5 + i].set(pf.vcoef[i])
+        (c_pad,) = _pad_cast([c], block_m, None, None, [False])
+        x = fused_cn_penta.fused_cn_penta_pallas(
+            lhs, pf.Z, pf.Minv, params, c_pad, block_m=block_m,
+            unroll=unroll, interpret=interpret)
+        return _unpad(x, n, m)
 
 
 # ---------------------------------------------------------------------------

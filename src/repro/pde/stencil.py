@@ -7,6 +7,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
+
 
 def apply_periodic_stencil(field: jax.Array, weights) -> jax.Array:
     """Apply a centred periodic stencil along axis 0 of ``field``.
@@ -16,12 +18,13 @@ def apply_periodic_stencil(field: jax.Array, weights) -> jax.Array:
     """
     weights = list(weights)
     r = (len(weights) - 1) // 2
-    out = jnp.zeros_like(field)
-    for k, w in enumerate(weights):
-        off = k - r
-        if w == 0:
-            continue
-        out = out + w * jnp.roll(field, -off, axis=0)
+    with jax.named_scope(scopes.STENCIL):
+        out = jnp.zeros_like(field)
+        for k, w in enumerate(weights):
+            off = k - r
+            if w == 0:
+                continue
+            out = out + w * jnp.roll(field, -off, axis=0)
     return out
 
 

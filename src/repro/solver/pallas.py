@@ -286,9 +286,9 @@ def solve_stored(bandwidth: int, mode: str, periodic: bool, stored,
         elif periodic:
             pf = stored
             y = _kops.thomas_constant(pf.factor, rhs, **kw)
-            # rank-1 Sherman-Morrison corner correction (paper Eq. 15)
-            v_dot_y = y[0] + pf.v_last * y[-1]
-            x = y - (v_dot_y * pf.inv_denom_sm) * pf.z[:, None]
+            # rank-1 Sherman-Morrison corner correction (paper Eq. 15),
+            # shared with the reference sweeps
+            x = _tridiag.periodic_corner_correction(pf, y)
         else:
             x = _kops.thomas_constant(stored, rhs, **kw)
     else:
