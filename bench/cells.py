@@ -4,7 +4,9 @@ A configuration (``configs/<name>.json``) gives the operator and its sizes;
 a traffic mix (``traffic/<name>.json``) gives what one timed call does.
 The traffic's ``kind`` names the module ``kinds/<kind>.py`` that builds the
 cell: its ``build(config, traffic, seed, devices, control=...)`` returns a
-``Cell``.  A new kind of call is a new module there; a new mix of a kind
+``Cell``, and its ``FAULTS`` maps the name of each fault its cells can
+have to a wrapper of the timed call that plants it (``faults``).  A new
+kind of call, with its faults, is a new module there; a new mix of a kind
 that exists is a new data file.
 
 With ``control=True`` the kind puts its control, a reference computed in
@@ -36,10 +38,8 @@ class Cell:
     memory: Callable[[], dict | None] = lambda: None
 
 
-def build(config: dict, traffic: dict, seed: int, devices: list, *,
-          control: bool = False) -> Cell:
-    """The cell ``config`` x ``traffic`` on ``devices``, made from ``seed``
-    by ``kinds/<traffic["kind"]>.py``."""
+def _kind(traffic: dict):
+    """The module ``kinds/<traffic["kind"]>.py``."""
     kind = traffic["kind"]
     path = KINDS / f"{kind}.py"
     if not path.is_file():
@@ -48,7 +48,59 @@ def build(config: dict, traffic: dict, seed: int, devices: list, *,
     spec = importlib.util.spec_from_file_location(f"bench_kind_{kind}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.build(config, traffic, seed, devices, control=control)
+    return module
+
+
+def build(config: dict, traffic: dict, seed: int, devices: list, *,
+          control: bool = False) -> Cell:
+    """The cell ``config`` x ``traffic`` on ``devices``, made from ``seed``
+    by ``kinds/<traffic["kind"]>.py``."""
+    return _kind(traffic).build(config, traffic, seed, devices,
+                                control=control)
+
+
+def faults(traffic: dict) -> dict:
+    """{name: wrapper of the timed call} of every fault that a cell of
+    ``traffic``'s kind can have: the kind's ``FAULTS``.  The comparison
+    that decides ``correct`` has to fail each."""
+    return dict(_kind(traffic).FAULTS)
+
+
+# Faults of a kind whose state and output are one (N, M) array, N unknowns
+# of M independent systems, each planted in the output where it is
+# produced.  A chained kind donates its state to the call, so a fault that
+# still reads its input runs the call on a copy.
+
+def unchanged(call):
+    """The state returned unchanged."""
+    def broken(state):
+        return state
+    return broken
+
+
+def half_batch(call):
+    """Half of the systems left out: their columns keep the input."""
+    import jax.numpy as jnp
+
+    def broken(state):
+        out = call(jnp.copy(state))
+        half = out.shape[1] // 2
+        return out.at[:, half:].set(state[:, half:])
+    return broken
+
+
+def altered_answer(call):
+    """One answer of every system altered."""
+    import jax.numpy as jnp
+
+    def broken(state):
+        out = call(jnp.copy(state))
+        return out.at[out.shape[0] // 2].add(0.1)
+    return broken
+
+
+ARRAY_FAULTS = {"unchanged": unchanged, "half_batch": half_batch,
+                "altered_answer": altered_answer}
 
 
 def columns_reader(cols: np.ndarray) -> Callable[[Any], np.ndarray]:

@@ -21,6 +21,8 @@ import control as ctl
 import counts
 import reference as ref
 
+FAULTS = cells.ARRAY_FAULTS
+
 
 def build(config: dict, traffic: dict, seed: int, devices: list, *,
           control: bool = False) -> cells.Cell:

@@ -26,6 +26,10 @@ import control as ctl
 import counts
 import reference as ref
 
+#: The sharded solve splits independent systems by columns and exchanges
+#: nothing between chips, so no exchange can be left out.
+FAULTS = cells.ARRAY_FAULTS
+
 
 def build(config: dict, traffic: dict, seed: int, devices: list, *,
           control: bool = False) -> cells.Cell:

@@ -2,8 +2,7 @@
 its ``repro.corner_correction`` scope (the periodic Sherman-Morrison or
 Woodbury correction and the reductions that feed it), on the chip that
 spent most on them; each operation's layer is read from its ``tf_op`` in
-the trace (``scopes.py``).  None where the trace was reduced without
-layers, or no operation carries the scope."""
+the trace (``scopes.py``).  None where no operation carries the scope."""
 
 import scopes
 

@@ -14,8 +14,10 @@ from the seed: every number that ``bench/limits/<cell>.json`` names is
 checked against its limit.
 
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
-per-layer metrics, read from a profiler trace of the window.  Each metric
-is read by ``bench/metrics/<name>.py``.  Without a TPU, or with fewer chips
+per-layer metrics, read from a profiler trace of the window with each
+device operation's layer (``scopes.py``); the result's ``diagnostics``
+then carry the time of every layer under ``scopes``.  Each metric is read
+by ``bench/metrics/<name>.py``.  Without a TPU, or with fewer chips
 than the cell asks for, the run exits with status 2 and prints no result.
 
 ``--control`` puts the kind's control, the reference in bfloat16, in the
@@ -66,7 +68,7 @@ class Run:
     counts: dict                    # the cell's algorithmic counts per call
     memory_peak_bytes: int
     peaks: dict | None = None
-    trace: Any = None               # devtrace.Summary of the window
+    trace: Any = None               # scopes.ScopedSummary of the window
 
 
 def load_cell(workload: str, entry: dict | None = None) -> dict:
@@ -187,10 +189,15 @@ def execute(workload: str, seed: int, seconds: float, trace: bool, *,
             trace_dir: str | None = None, entry: dict | None = None):
     """One run of ``workload``: (result line, checks).
 
-    ``sizes`` overrides configuration sizes, ``wrap_call`` wraps the timed
-    call and ``entry`` stands in for the cell's entry (``load_cell``); all
-    three exist for the CPU rehearsal and its fault tests, which also pass
-    ``require_tpu=False``."""
+    ``sizes`` overrides configuration keys, ``wrap_call`` wraps the timed
+    call (a fault of the cell's kind, ``cells.faults``) and ``entry``
+    stands in for the cell's entry (``load_cell``); all three exist for
+    the CPU rehearsal and its fault tests, which also pass
+    ``require_tpu=False``.  The rehearsal takes ``sizes`` from the cell's
+    ``rehearsal/<cell>.json``, which may shrink any key, a model
+    configuration's widths among them, so that a CPU test run can hold
+    the cell; a chip run never reads that file, and its command line has
+    no way to pass ``sizes``."""
     import jax
 
     import cells
@@ -254,8 +261,9 @@ def execute(workload: str, seed: int, seconds: float, trace: bool, *,
               memory_peak_bytes=memory_peak, peaks=peaks)
     if trace:
         import devtrace
-        run.trace = devtrace.summarize(devtrace.find_xplane(log_dir),
-                                       device_ids=[d.id for d in used])
+        import scopes
+        run.trace = scopes.summarize(devtrace.find_xplane(log_dir),
+                                     device_ids=[d.id for d in used])
         if trace_dir is None:
             shutil.rmtree(log_dir, ignore_errors=True)
 
@@ -294,6 +302,8 @@ def execute(workload: str, seed: int, seconds: float, trace: bool, *,
         "memory_stats": used[0].memory_stats() or {},
         "compiled_memory_bytes": _compiled_memory(cell),
         "numbers": numbers}
+    if trace:
+        result["diagnostics"]["scopes"] = run.trace.table(calls)
     result["checks"] = checks
     return result, checks
 

@@ -104,11 +104,9 @@ def _per_call_ms(per_device: list, calls: int):
 
 
 def layer_ms(run, layer: str):
-    """Device ms per call that ``run``'s trace spent in ``layer``, on the
-    device that spent most; None where the trace carries no layers (a
-    ``devtrace.Summary``) or nothing ran in ``layer``."""
-    if not isinstance(run.trace, ScopedSummary):
-        return None
+    """Device ms per call that ``run``'s trace (a ``ScopedSummary``) spent
+    in ``layer``, on the device that spent most; None where nothing ran in
+    ``layer``."""
     return _per_call_ms(run.trace.layer_s(layer), run.calls)
 
 

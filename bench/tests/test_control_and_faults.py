@@ -2,20 +2,21 @@
 every fault the cells can have, driven through the rest of a run on the
 CPU at a tiny size.
 
-Faults, each planted in the timed call's output where it is produced:
-the state returned unchanged, half of the batch left out, and one answer
-of every system altered.  The cells exchange nothing between chips (the
-sharded solve splits independent systems), so no exchange can be left
-out."""
+Each kind (``kinds/<kind>.py``) declares the faults its cells can have,
+``FAULTS``, and each is planted in the timed call's output where it is
+produced; for the (N, M) arrays of ``cn_step`` and ``solve`` these are
+``cells.ARRAY_FAULTS``: the state returned unchanged, half of the batch
+left out, and one answer of every system altered."""
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import cells
 import control
 import reference as ref
 import run
-from test_rehearsal import CELLS, ENTRIES, SEED, TINY
+from test_rehearsal import CELLS, ENTRIES, SEED, TINY, traffic
 
 
 @pytest.mark.parametrize("workload", CELLS)
@@ -26,37 +27,18 @@ def test_control_is_not_correct(workload):
     assert result["correct"] is False, checks
 
 
-# The CN cells donate their field to the call, so a fault that still reads
-# its input runs the call on a copy.
-
-def unchanged(call):
-    def broken(state):
-        return state
-    return broken
+FAULT_CASES = [pytest.param(workload, name, id=f"{workload}-{name}")
+               for workload in CELLS for name in cells.faults(
+                   traffic(workload))]
 
 
-def half_batch(call):
-    def broken(state):
-        out = call(jnp.copy(state))
-        half = out.shape[1] // 2
-        return out.at[:, half:].set(state[:, half:])
-    return broken
-
-
-def altered_answer(call):
-    def broken(state):
-        out = call(jnp.copy(state))
-        return out.at[out.shape[0] // 2].add(0.1)
-    return broken
-
-
-@pytest.mark.parametrize("fault", [unchanged, half_batch, altered_answer])
-@pytest.mark.parametrize("workload", CELLS)
+@pytest.mark.parametrize("workload,fault", FAULT_CASES)
 def test_fault_is_not_correct(workload, fault):
+    wrap = cells.faults(traffic(workload))[fault]
     result, checks = run.execute(workload, SEED, 0.2, False,
                                  require_tpu=False, sizes=TINY[workload],
-                                 wrap_call=fault, entry=ENTRIES[workload])
-    assert result["correct"] is False, (fault.__name__, checks)
+                                 wrap_call=wrap, entry=ENTRIES[workload])
+    assert result["correct"] is False, (fault, checks)
 
 
 # The controls' algorithms, run in float32, agree with the float64

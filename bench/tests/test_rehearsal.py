@@ -7,33 +7,29 @@ import pathlib
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 import run
 
-#: Sizes a CPU test run can hold; the widths of the cells are their own.
-TINY = {
-    "diffusion-shared-lhs": {"m": 256},
-    "hyperdiffusion-shared-lhs": {"m": 256},
-    "tridiag-copied-lhs": {"m": 256},
-    "tridiag-n16384-sharded-x4": {"m": 512, "n": 512},
-}
 SEED = 2 ** 31 + 977           # larger than 32 signed bits hold
 
-
-#: Cells staged in bench/ (configuration, traffic, limits) but not yet in
-#: BENCHMARK.json, because they are not yet proven on the chip: rehearsed
-#: all the same, so that their path stays tested.
-STAGED = [
-    {"name": "tridiag-copied-lhs", "config": "tridiag-n1024",
-     "traffic": "copied-lhs-solve", "chips": 1},
-    {"name": "tridiag-n16384-sharded-x4", "config": "tridiag-n16384-x4",
-     "traffic": "sharded-solve", "chips": 4},
-]
-ENTRIES = {w["name"]: w for w in STAGED}
+#: Each cell's CPU rehearsal, ``rehearsal/<cell>.json``: the ``sizes`` a CPU
+#: test run can hold and, for a cell staged in bench/ (configuration,
+#: traffic, limits) but not yet in BENCHMARK.json because it is not yet
+#: proven on the chip, its ``entry``, so that its path stays tested.
+REHEARSALS = {path.stem: json.loads(path.read_text())
+              for path in sorted((run.BENCH / "rehearsal").glob("*.json"))}
+TINY = {name: r["sizes"] for name, r in REHEARSALS.items()}
+ENTRIES = {name: r["entry"] for name, r in REHEARSALS.items()
+           if "entry" in r}
 ENTRIES.update((w["name"], w) for w in json.loads(
     (run.ROOT / "BENCHMARK.json").read_text())["workloads"])
 CELLS = sorted(ENTRIES)
+
+
+def traffic(workload: str) -> dict:
+    return run.load_cell(workload, ENTRIES[workload])["traffic"]
 
 
 def test_every_cell_has_a_tiny_size():
@@ -64,17 +60,28 @@ def test_rehearse_cell(workload):
 
 def test_same_seed_same_inputs():
     # the same seed gives the same data: the step count differs between
-    # runs, so compare the inputs the cell was built from
-    import cells
-    spec = run.load_cell(CELLS[0], ENTRIES[CELLS[0]])
-    config = dict(spec["config"], **TINY[CELLS[0]])
+    # runs, so compare the inputs the cell was built from, leaf by leaf,
+    # for one cell of each kind
     import jax
+
+    import cells
     device = jax.devices()[:1]
-    a = cells.build(config, spec["traffic"], SEED, device)
-    b = cells.build(config, spec["traffic"], SEED, device)
-    c = cells.build(config, spec["traffic"], SEED + 1, device)
-    assert (a.state == b.state).all()
-    assert not (a.state == c.state).all()
+    first = {}
+    for workload in CELLS:
+        first.setdefault(traffic(workload)["kind"], workload)
+    for workload in first.values():
+        spec = run.load_cell(workload, ENTRIES[workload])
+        config = dict(spec["config"], **TINY[workload])
+
+        def leaves(seed):
+            cell = cells.build(config, spec["traffic"], seed, device)
+            return [np.asarray(x)
+                    for x in jax.tree_util.tree_leaves(cell.state)]
+
+        a, b, c = leaves(SEED), leaves(SEED), leaves(SEED + 1)
+        assert len(a) == len(b) == len(c) > 0, workload
+        assert all((x == y).all() for x, y in zip(a, b)), workload
+        assert not all((x == z).all() for x, z in zip(a, c)), workload
 
 
 def test_a_number_the_cell_does_not_give_fails(monkeypatch):

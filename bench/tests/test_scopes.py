@@ -6,6 +6,7 @@ import pathlib
 
 import pytest
 
+import counts
 import devtrace
 import run
 import scopes
@@ -85,13 +86,10 @@ def test_a_trace_without_scopes_is_all_unscoped(unscoped):
 
 @pytest.mark.parametrize("name", ["stencil_ms", "corner_correction_ms"])
 def test_new_metrics_are_silent_without_scopes(unscoped, name):
-    """The parent program names no layers: the readers give None, and on
-    a trace reduced by ``devtrace`` alone too."""
-    for trace in (unscoped, devtrace.summarize(UNSCOPED_TRACE,
-                                               device_ids=[0])):
-        r = run.Run(setup_s=0.0, calls=16, window_s=1.0, call_s=[],
-                    counts={}, memory_peak_bytes=0, trace=trace)
-        assert run.read_metric(name, r) is None
+    """The parent program names no layers: the readers give None."""
+    r = run.Run(setup_s=0.0, calls=16, window_s=1.0, call_s=[],
+                counts={}, memory_peak_bytes=0, trace=unscoped)
+    assert run.read_metric(name, r) is None
 
 
 def test_scoped_summary_keeps_every_devtrace_reading(unscoped):
@@ -167,3 +165,40 @@ def test_every_op_is_scoped_but_async_copies_of_constants(scoped):
                for name in table["unscoped"]["ops"])
     assert table[scopes.STENCIL]["ms"] > 0
     assert table[scopes.CORNER_CORRECTION]["ms"] > 0
+
+
+#: every per-layer metric the benchmark names
+PER_LAYER = [m["name"] for m in json.loads(
+    (run.ROOT / "BENCHMARK.json").read_text())["per_layer"]]
+#: the metrics of the program's layers, which only the scoped reduction reads
+LAYER_METRICS = {"stencil_ms", "corner_correction_ms"}
+
+
+@pytest.mark.parametrize("name", PER_LAYER)
+@pytest.mark.parametrize("path", sorted(DATA.glob("*.xplane.pb")),
+                         ids=lambda p: p.name.split(".")[0])
+def test_run_reads_every_metric_as_devtrace_did(path, name):
+    """``run.py`` reduces a trace with ``scopes.summarize``: every metric
+    that ``devtrace.summarize`` gave reads bit-equal through it, and the
+    layer metrics read what ``scopes.py``'s report of the trace gave (None
+    on the trace recorded before the program named its layers)."""
+    recorded = json.loads(path.with_name(
+        path.name.replace(".xplane.pb", ".json")).read_text())
+    words = counts.cn_step_words(1024, 524288)  # every stored trace's cell
+
+    def read(summary):
+        r = run.Run(setup_s=0.0, calls=recorded["calls"],
+                    window_s=recorded["window_s"], call_s=[],
+                    counts={"step_bytes": 4 * words,
+                            "kernel_bytes": 4 * words},
+                    memory_peak_bytes=0,
+                    peaks=counts.peaks("TPU v5 lite"), trace=summary)
+        return run.read_metric(name, r)
+
+    new = read(scopes.summarize(path, device_ids=[0]))
+    if name in LAYER_METRICS:
+        reported = recorded.get("scopes_report", {"metrics": {}})["metrics"]
+        assert new == pytest.approx(reported.get(name), rel=1e-12)
+    else:
+        old = read(devtrace.summarize(path, device_ids=[0]))
+        assert old is not None and new == old
