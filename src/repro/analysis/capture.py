@@ -25,9 +25,10 @@ From the records two independent recounts are derived:
     from the specs: VMEM blocks of the in/out specs are the pipelined
     (double-buffered) blocks, SMEM blocks are the stacked shared LHS
     (their row count), small ``(c, block_m)`` scratch rows are the sweep
-    carries, and lane-tiled scratch spanning the FULL output N extent is
-    the full-N scratch (``SweepSpec.sweep_scratch``: the fused kernels'
-    intermediate, the resident batch kernel's factor coefficients).  The
+    carries, and scratch holding a whole lane block over the FULL output
+    N extent is the full-N scratch (``SweepSpec.sweep_scratch``: the fused
+    kernels' intermediate, the resident batch kernel's factor
+    coefficients, the packed sweep's lane-tile-major copy).  The
     streamed pair reports the elementwise max over its two kernels (the
     forward's larger set — exactly what the budget check reasons with).
 
@@ -46,7 +47,7 @@ import math
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import block_shape_of, in_smem, index_map_of
+from repro.kernels.common import PACK, block_shape_of, in_smem, index_map_of
 from repro.kernels.engine import (SweepSpec, batch_solver, recurrence_solver,
                                   shared_solver)
 
@@ -57,6 +58,18 @@ from repro.kernels.engine import (SweepSpec, batch_solver, recurrence_solver,
 #: row count (<= 6).
 TRACE_N, TRACE_M = 48, 24
 TRACE_BLOCK_M, TRACE_BLOCK_N = 8, 16
+#: The packed sweep's lane tile holds whole packed groups (1024 lanes
+#: each): it is traced at two groups per tile and three tiles.
+TRACE_PACKED_BLOCK_M = 2 * PACK
+
+
+def trace_geometry(spec) -> tuple:
+    """``(n, m, block_m)`` the checkers trace ``spec`` at: the reference
+    shapes above, or for a packed spec a lane tile of whole packed
+    groups."""
+    if getattr(spec, "packed", False):
+        return TRACE_N, 3 * TRACE_PACKED_BLOCK_M, TRACE_PACKED_BLOCK_M
+    return TRACE_N, TRACE_M, TRACE_BLOCK_M
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,13 +134,14 @@ def capture_pallas_calls():
         pl.pallas_call = real
 
 
-def trace_spec_calls(spec, *, n: int = TRACE_N, m: int = TRACE_M,
-                     block_m: int = TRACE_BLOCK_M,
-                     block_n: int = TRACE_BLOCK_N) -> list:
-    """Drive ``spec``'s builder on dummy operands, returning the captured
-    ``CallRecord`` list — ``spec.num_pallas_calls`` records: one for
-    resident variants and for every recurrence (single-pass), the
+def trace_spec_calls(spec, *, block_n: int = TRACE_BLOCK_N,
+                     geometry: tuple | None = None) -> list:
+    """Drive ``spec``'s builder on dummy operands at ``geometry``
+    (``(n, m, block_m)``, default its ``trace_geometry``), returning the
+    captured ``CallRecord`` list — ``spec.num_pallas_calls`` records: one
+    for resident variants and for every recurrence (single-pass), the
     forward/backward pair for streamed sweeps."""
+    n, m, block_m = geometry or trace_geometry(spec)
     assert m % block_m == 0 and n % block_n == 0
     args, eps = spec.dummy_args(n, m)
     kwargs = dict(block_m=block_m, interpret=True)
@@ -174,9 +188,11 @@ def recount_vmem_counts(records: list, *, block_m: int = TRACE_BLOCK_M
     n_sweep_scratch)`` recount — the elementwise max over the captured
     kernels' per-grid-step sets.
 
-    The fourth slot counts full-N VMEM scratch: lane-tiled scratch whose
-    N extent matches the full output sweep (the fused kernels'
-    intermediates, the resident batch kernel's coefficients)."""
+    The fourth slot counts full-N VMEM scratch: scratch holding
+    ``block_m`` lanes over the full output sweep, in any arrangement (the
+    fused kernels' intermediates, the resident batch kernel's
+    coefficients, the packed sweep's ``(block_m / 128 * N, 128)``
+    lane-tile-major copy)."""
     counts = (0, 0, 0, 0)
     for rec in records:
         blocks = lhs = carry = sweep = 0
@@ -191,7 +207,7 @@ def recount_vmem_counts(records: list, *, block_m: int = TRACE_BLOCK_M
                 blocks += 1          # a pipelined VMEM block
         for scratch in rec.scratch_shapes:
             shape = tuple(scratch.shape)
-            if shape[-1] == block_m and shape[0] in n_extents:
+            if math.prod(shape) in {n * block_m for n in n_extents}:
                 sweep += 1           # full-N scratch
             else:
                 carry += shape[0]    # carry rows

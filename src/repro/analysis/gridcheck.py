@@ -49,7 +49,7 @@ from repro.kernels import engine
 from repro.kernels.common import block_shape_of, index_map_of
 
 from . import Finding
-from .capture import trace_spec_calls
+from .capture import trace_geometry, trace_spec_calls
 
 _SENTINEL = 0.37  # finite, nonzero, far from any legit zero-carry value
 
@@ -282,8 +282,8 @@ class _MockRef:
     def _one(ix):
         if hasattr(ix, "start") and hasattr(ix, "size") and \
                 not isinstance(ix, slice):          # pl.ds -> Slice
-            start = int(ix.start)
-            return slice(start, start + int(ix.size))
+            start, stride = int(ix.start), int(ix.stride)
+            return slice(start, start + int(ix.size) * stride, stride)
         return ix
 
     def _key(self, key):
@@ -294,7 +294,9 @@ class _MockRef:
         return self._one(key)
 
     def __getitem__(self, key):
-        return jnp.asarray(self.arr[self._key(key)])
+        # a copy: jnp.asarray may alias the numpy view, which the kernel's
+        # later stores would then change under a pending computation
+        return jnp.asarray(np.array(self.arr[self._key(key)]))
 
     def __setitem__(self, key, val):
         self.arr[self._key(key)] = np.asarray(val)
@@ -413,9 +415,36 @@ def _check_carry_protocol(spec, records, out: list) -> None:
                     f"carry)"))
 
 
+def _check_packed_layout(spec, rec, out: list) -> None:
+    """The packed kernel body and its row sibling's, both run on the host
+    on one block of the same data, must agree bit for bit: a wrong group
+    base or stride in the lane-tile-major layout sweeps other systems'
+    rows (or the same row twice), which no index map shows."""
+    row_rec = trace_spec_calls(engine.REGISTRY[spec.row_name],
+                               geometry=trace_geometry(spec))[0]
+    in_data = _operand_data(spec, rec, np.random.default_rng(5))
+    try:
+        got = _run_probe(rec, in_data, 0.0, (0,))[0]
+    except (IndexError, ValueError) as exc:
+        out.append(Finding("gridcheck", spec.name,
+                           f"packed layout addresses outside its scratch "
+                           f"({type(exc).__name__}: {exc})"))
+        return
+    want = _run_probe(row_rec, in_data, 0.0, (0,))[0]
+    if not np.array_equal(got, want):
+        bad = np.argwhere(got != want)
+        out.append(Finding(
+            "gridcheck", spec.name,
+            f"packed layout differs from the row sweep at "
+            f"{len(bad)} of {got.size} elements (first at row, lane "
+            f"{tuple(int(v) for v in bad[0])}) — a wrong group base or "
+            f"stride in the lane-tile-major scratch"))
+
+
 def run() -> list:
     """All gridcheck invariants over every registered streamed spec (the
-    resident kernels have a trivial 1-D grid, checked for coverage too)."""
+    resident kernels have a trivial 1-D grid, checked for coverage too;
+    packed kernels are also run against their row sibling)."""
     out: list = []
     for name in sorted(engine.REGISTRY):
         spec = engine.REGISTRY[name]
@@ -427,6 +456,8 @@ def run() -> list:
             else:
                 _check_write_coverage(spec, rec, out)
             _check_read_bounds(spec, rec, out)
+        if getattr(spec, "packed", False):
+            _check_packed_layout(spec, records[0], out)
         if not spec.streamed:
             continue
         if len(records) != spec.num_pallas_calls:

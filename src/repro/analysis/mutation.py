@@ -42,6 +42,12 @@ of the CUDA solvers the paper benchmarks):
      never errors) and the back-substitution silently overwrites one
      block ``num_n`` times.  ``gridcheck``'s fused walk/coverage checks
      flag the missing mirror.
+  8. **wrong packed group base** — the packed sweep addressing group g
+     at ``g * n`` instead of ``g * 8 * n`` in its lane-tile-major
+     scratch: group 0 is right, every later group sweeps lane tiles of
+     group 0 a second time and leaves its own unsolved.  A one-group
+     test never sees it; ``gridcheck`` runs the packed body against its
+     row sibling on two groups and flags the layout.
 """
 
 from __future__ import annotations
@@ -149,6 +155,20 @@ def _forgotten_descend_mirror():
 
 
 @contextlib.contextmanager
+def _wrong_packed_group_base():
+    orig = engine._packed_rows
+
+    def bad(n, g, i):
+        return pl.ds(g * n + i, engine.SUBLANE, stride=n)
+
+    engine._packed_rows = bad
+    try:
+        yield
+    finally:
+        engine._packed_rows = orig
+
+
+@contextlib.contextmanager
 def _stale_traffic_constant():
     orig = engine.SweepSpec.traffic_words
 
@@ -217,6 +237,8 @@ _MUTATIONS = (
      speccheck.run, "gate operand"),
     ("forgotten-descend-mirror", _forgotten_descend_mirror,
      gridcheck.run, "mirror"),
+    ("wrong-packed-group-base", _wrong_packed_group_base,
+     gridcheck.run, "packed layout"),
 )
 
 

@@ -20,6 +20,11 @@ under ``jax_debug_nans`` on three shape classes:
 
 Any non-finite value in an intermediate raises immediately (debug-nans),
 and the sliced outputs are additionally checked finite.
+
+A packed spec engages only on whole packed groups (N % 8 == 0, M % 1024
+== 0), so it runs each case embedded in the smallest such operand, zero
+outside the case as a caller's padding leaves it: its dead lanes and rows
+then ride through the packed sweep itself.
 """
 
 from __future__ import annotations
@@ -31,6 +36,7 @@ import jax.numpy as jnp
 
 from repro.core import penta_factor, thomas_factor
 from repro.kernels import engine, ops
+from repro.kernels.common import PACK, SUBLANE
 
 from . import Finding
 
@@ -83,6 +89,15 @@ def _dispatch(spec, rng, n, m, block_m, block_n):
     """One solve of ``spec`` through its ops entry point; returns (n, m)."""
     fn = ops.entry_point(spec)
     rhs = jnp.asarray(rng.uniform(-1, 1, (n, m)).astype(np.float32))
+    if getattr(spec, "packed", False):
+        full = (-(-n // SUBLANE) * SUBLANE, -(-m // PACK) * PACK)
+        rhs = jnp.zeros(full, rhs.dtype).at[:n, :m].set(rhs)
+        f = _shared_factor(spec, rng, full[0])
+        kwargs = dict(block_m=block_m, interpret=True,
+                      transposed=spec.transposed)
+        if spec.bandwidth == 5:
+            kwargs["uniform"] = spec.uniform
+        return fn(f, rhs, **kwargs)[:n, :m]
     bn = block_n if spec.streamed else None
     if spec.layout == "recurrence":
         return fn(*_recurrence_gates(spec, rng, n, m), rhs,

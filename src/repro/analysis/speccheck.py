@@ -42,7 +42,7 @@ from repro.kernels.engine import EPS_PARAM, RecurrenceSpec, SweepSpec
 
 from . import Finding
 from .capture import (TRACE_M, TRACE_N, recount_traffic_words,
-                      recount_vmem_counts, trace_spec_calls)
+                      recount_vmem_counts, trace_geometry, trace_spec_calls)
 
 
 def _lags(pspec) -> tuple:
@@ -265,6 +265,33 @@ def _check_fused_sibling(spec, out: list) -> None:
                            f"nothing"))
 
 
+def _check_packed_sibling(spec, out: list) -> None:
+    """A packed spec is its row sibling with 8 lane tiles on the
+    sublanes: same pass table, same modelled HBM traffic (packing moves
+    lanes within VMEM, never arithmetic or HBM streams)."""
+    if not getattr(spec, "packed", False):
+        return
+    row = engine.REGISTRY.get(spec.row_name)
+    if row is None:
+        out.append(Finding("speccheck", spec.name,
+                           f"row sibling {spec.row_name!r} is not "
+                           f"registered (packed specs need their fallback "
+                           f"for shapes the packing does not fit)"))
+        return
+    if spec.passes() != row.passes():
+        out.append(Finding("speccheck", spec.name,
+                           "packed variant runs a different pass table "
+                           "than its row sibling (packing must move lanes "
+                           "onto sublanes, never arithmetic)"))
+    n, m, _ = trace_geometry(spec)
+    if spec.traffic_words(n, m) != row.traffic_words(n, m):
+        out.append(Finding("speccheck", spec.name,
+                           f"packed traffic ({spec.traffic_words(n, m)} "
+                           f"words) differs from the row sibling's "
+                           f"({row.traffic_words(n, m)}) — the relayout "
+                           f"is in VMEM, HBM streams are the same"))
+
+
 def _check_accounting(spec, out: list) -> None:
     """Recount traffic + VMEM from the captured builders; exact match."""
     records = trace_spec_calls(spec)
@@ -274,16 +301,17 @@ def _check_accounting(spec, out: list) -> None:
                            f"builder emitted {len(records)} pallas_call(s), "
                            f"expected {want_calls}"))
         return
+    n, m, block_m = trace_geometry(spec)
     got = recount_traffic_words(records)
-    want = spec.traffic_words(TRACE_N, TRACE_M)
+    want = spec.traffic_words(n, m)
     if got != want:
         out.append(Finding(
             "speccheck", spec.name,
             f"HBM traffic drift: builders move {got} words at "
-            f"(N={TRACE_N}, M={TRACE_M}) but SweepSpec.traffic_words "
+            f"(N={n}, M={m}) but SweepSpec.traffic_words "
             f"claims {want} — the roofline model no longer matches the "
             f"code"))
-    got_vmem = recount_vmem_counts(records)
+    got_vmem = recount_vmem_counts(records, block_m=block_m)
     want_vmem = tuple(spec.vmem_counts()) + (spec.sweep_scratch(),)
     labels = ("blocks", "lhs_rows", "carry_rows", "sweep_scratch")
     if got_vmem != want_vmem:
@@ -349,6 +377,7 @@ def run() -> list:
             _check_twin(spec, out)
         _check_streamed_sibling(spec, out)
         _check_fused_sibling(spec, out)
+        _check_packed_sibling(spec, out)
         _check_accounting(spec, out)
         _check_storage_pricing(spec, out)
         _check_sharded_traffic(spec, out)

@@ -24,8 +24,17 @@ from jax.experimental.pallas import tpu as pltpu
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 SMEM_BUDGET_BYTES = 1024 * 1024
+# The packed shared sweep (``SweepSpec.packed``) sweeps 1024 systems per
+# vreg, so its lane tile is a multiple of 1024 and its working set (two
+# pipelined blocks plus a lane-tile-major copy of the block) outgrows the
+# default scoped budget.  It asks for more of the 128 MiB of VMEM a v5e
+# core has: the budget its fit check prices, and the limit it requests,
+# which leaves the same 16 MiB of headroom for Mosaic's internal scratch.
+PACKED_VMEM_BUDGET_BYTES = 80 * 1024 * 1024
+PACKED_VMEM_LIMIT_BYTES = 96 * 1024 * 1024
 LANE = 128          # TPU lane width — one system per lane (paper: one per thread)
 SUBLANE = 8         # VREG sublane depth — sweep unroll granularity
+PACK = SUBLANE * LANE   # systems one whole (8, 128) vreg holds (packed sweep)
 
 
 def default_interpret() -> bool:
@@ -188,10 +197,24 @@ def spec_working_set(spec, n: int, block_m: int, block_n: int | None = None,
     return vmem, smem
 
 
+def vmem_budget(spec) -> int:
+    """The VMEM budget ``spec``'s fit check prices against."""
+    if getattr(spec, "packed", False):
+        return PACKED_VMEM_BUDGET_BYTES
+    return VMEM_BUDGET_BYTES
+
+
+def vmem_limit(spec) -> int:
+    """The scoped VMEM limit ``spec``'s compiled kernel requests."""
+    if getattr(spec, "packed", False):
+        return PACKED_VMEM_LIMIT_BYTES
+    return VMEM_LIMIT_BYTES
+
+
 def spec_fits(spec, n: int, block_m: int, block_n: int | None = None,
               itemsize: int = 4) -> bool:
     vmem, smem = spec_working_set(spec, n, block_m, block_n, itemsize)
-    return vmem <= VMEM_BUDGET_BYTES and smem <= SMEM_BUDGET_BYTES
+    return vmem <= vmem_budget(spec) and smem <= SMEM_BUDGET_BYTES
 
 
 def check_fits(spec, n: int, block_m: int, block_n: int | None = None,
@@ -201,7 +224,8 @@ def check_fits(spec, n: int, block_m: int, block_n: int | None = None,
     vmem, smem = spec_working_set(spec, n, block_m, block_n, itemsize)
     where = (f"N={n}, BLOCK_M={block_m}" if block_n is None
              else f"N={n}, BLOCK_N={block_n}, BLOCK_M={block_m}")
-    if vmem > VMEM_BUDGET_BYTES:
+    budget = vmem_budget(spec)
+    if vmem > budget:
         hint = ("reduce block_m or split N (HBM-streamed variant)"
                 if block_n is None else
                 "the full-N scratch does not fit — spill to the two-call "
@@ -209,7 +233,7 @@ def check_fits(spec, n: int, block_m: int, block_n: int | None = None,
                 if spec.fused else "reduce block_n or block_m")
         raise ValueError(
             f"{spec.name} working set {vmem/2**20:.1f} MiB exceeds VMEM "
-            f"budget ({VMEM_BUDGET_BYTES/2**20:.0f} MiB): {where}. {hint}.")
+            f"budget ({budget/2**20:.0f} MiB): {where}. {hint}.")
     if smem > SMEM_BUDGET_BYTES:
         raise ValueError(
             f"{spec.name} shared LHS block {smem/2**10:.0f} KiB exceeds SMEM "

@@ -19,7 +19,8 @@ This module makes that observation executable (DESIGN.md §2.2):
   * ``SweepSpec`` — the declarative description of one solver variant:
     bandwidth (3|5), layout (``shared`` factored LHS vs ``batch`` per-lane
     fused factorisation), ``transposed``, ``streamed`` (VMEM-resident vs
-    HBM-streamed split-N), ``uniform`` (penta shared only).
+    HBM-streamed split-N), ``uniform`` (penta shared only), ``packed``
+    (resident shared only: 8 lane tiles on the sublanes of each vreg).
   * ``PassSpec`` — one pass of the sweep: the ``(coefficient row,
     carry lag)`` terms in subtraction order plus an optional scale row.
     ``SweepSpec.passes()`` looks both passes up in ``_PASS_TABLE`` — the
@@ -63,9 +64,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import VMEM_LIMIT_BYTES, chunk_lhs_spec, chunk_spec, \
-    default_interpret, fused_chunk_spec, fused_lhs_spec, lhs_spec, \
-    param_spec, _imin, reset_carry, row, store_row, widen_rows
+from .common import LANE, SUBLANE, VMEM_LIMIT_BYTES, chunk_lhs_spec, \
+    chunk_spec, default_interpret, fused_chunk_spec, fused_lhs_spec, \
+    lhs_spec, param_spec, _imin, reset_carry, row, store_row, vmem_limit, \
+    widen_rows
 
 # Sentinel coefficient source: the uniform-mode eps value, which rides in a
 # (1, 1) ARRAY operand (never a Python float baked into the kernel closure,
@@ -152,6 +154,7 @@ class SweepSpec:
     streamed: bool = False    # HBM-streamed split-N vs VMEM-resident
     uniform: bool = False     # penta shared only: eps as a (1, 1) operand
     fused: bool = False       # streamed only: both passes in ONE kernel
+    packed: bool = False      # resident shared only: 8 lane tiles per vreg
 
     def __post_init__(self):
         if self.bandwidth not in (3, 5):
@@ -171,6 +174,11 @@ class SweepSpec:
                 "fused is a streamed concept: the resident kernels already "
                 "run both passes in one pallas_call; fused=True fuses the "
                 "STREAMED forward/backward pair onto one ascend/descend grid")
+        if self.packed and (self.layout != "shared" or self.streamed):
+            raise ValueError(
+                "packed is a resident shared-layout concept: it carries 8 "
+                "lane tiles on the sublanes of a VMEM-resident block swept "
+                "against one shared LHS")
 
     # -- derived structure --------------------------------------------------
 
@@ -196,12 +204,13 @@ class SweepSpec:
 
     @property
     def carry_rows(self) -> int:
-        """Rows of the (forward) kernel's VMEM carry scratch."""
+        """Rows of the (forward) kernel's VMEM carry scratch (0 for the
+        packed sweep, whose carries are whole vregs seeded as zeros)."""
         if self.layout == "batch":
             # factorisation carries: (c_hat, d_hat) or
             # (gamma x2, delta x2, g x2) lags
             return 2 if self.order == 1 else 6
-        return self.order
+        return 0 if self.packed else self.order
 
     @property
     def mode(self) -> str:
@@ -217,6 +226,8 @@ class SweepSpec:
             name += "_streamed"
         if self.fused:
             name += "_fused"
+        if self.packed:
+            name += "_packed"
         if self.transposed:
             name += "_t"
         return name
@@ -249,6 +260,16 @@ class SweepSpec:
     def resident_name(self) -> str:
         """Name of the VMEM-resident sibling (self when not streamed)."""
         return dataclasses.replace(self, streamed=False, fused=False).name
+
+    @property
+    def row_name(self) -> str:
+        """Name of the row-sweep sibling (self when not packed)."""
+        return dataclasses.replace(self, packed=False).name
+
+    @property
+    def packed_name(self) -> str:
+        """Name of the packed sibling of a resident shared spec."""
+        return dataclasses.replace(self, packed=True).name
 
     @property
     def unfused_name(self) -> str:
@@ -361,13 +382,14 @@ class SweepSpec:
         """Full-length (N, BLOCK_M) VMEM scratch arrays a kernel keeps
         across its whole sweep: the fused kernels' intermediate d_hat/g
         (plus, for batch layout, the factor coefficients) that the two-call
-        pair would spill to HBM, and the resident batch kernel's factor
-        coefficients.  0 for every other spec."""
+        pair would spill to HBM, the resident batch kernel's factor
+        coefficients, and the packed sweep's lane-tile-major copy of its
+        block.  0 for every other spec."""
         if self.fused:
             return 1 + self.n_coefs
         if self.layout == "batch" and not self.streamed:
             return self.n_coefs
-        return 0
+        return 1 if self.packed else 0
 
     @property
     def num_pallas_calls(self) -> int:
@@ -508,14 +530,15 @@ def _all_specs() -> tuple:
     specs = []
     for bw in (3, 5):
         for transposed in (False, True):
-            for streamed, fused in ((False, False), (True, False),
-                                    (True, True)):
-                specs.append(SweepSpec(bw, "shared", transposed=transposed,
-                                       streamed=streamed, fused=fused))
-                if bw == 5:
-                    specs.append(SweepSpec(bw, "shared", transposed=transposed,
+            for streamed, fused, packed in ((False, False, False),
+                                            (False, False, True),
+                                            (True, False, False),
+                                            (True, True, False)):
+                for uniform in ((False, True) if bw == 5 else (False,)):
+                    specs.append(SweepSpec(bw, "shared",
+                                           transposed=transposed,
                                            streamed=streamed, fused=fused,
-                                           uniform=True))
+                                           uniform=uniform, packed=packed))
         for streamed, fused in ((False, False), (True, False), (True, True)):
             specs.append(SweepSpec(bw, "batch", streamed=streamed,
                                    fused=fused))
@@ -668,6 +691,18 @@ def _lane_coeff(refs, off=0):
     return at
 
 
+def _sweep_step(acc, carries, pspec: PassSpec, coeff):
+    """One row of a pass: ``acc`` minus each term's coefficient times its
+    carry lag, in table order, then times the scale.  ``coeff(src)`` gives
+    the row's coefficient from source ``src``.  The one home of the sweep
+    arithmetic, so the row and packed sweeps cannot drift apart."""
+    for src, lag in pspec.terms:
+        acc = acc - coeff(src) * carries[lag - 1]
+    if pspec.scale is not None:
+        acc = acc * coeff(pspec.scale)
+    return acc
+
+
 def _solve_pass(coeff_at, in_ref, out_ref, init, *, pspec: PassSpec,
                 order: int, length: int, reverse: bool, unroll: int,
                 in_off=0, out_off=0):
@@ -685,15 +720,45 @@ def _solve_pass(coeff_at, in_ref, out_ref, init, *, pspec: PassSpec,
 
     def body(t, carries):
         i = length - 1 - t if reverse else t
-        acc = row(in_ref, in_at(i))
-        for src, lag in pspec.terms:
-            acc = acc - coeff_at(src, i) * carries[lag - 1]
-        if pspec.scale is not None:
-            acc = acc * coeff_at(pspec.scale, i)
+        acc = _sweep_step(row(in_ref, in_at(i)), carries, pspec,
+                          lambda src: coeff_at(src, i))
         store_row(out_ref, out_at(i), acc)
         return (acc,) + carries[:order - 1]
 
     return jax.lax.fori_loop(0, length, body, tuple(init), unroll=unroll)
+
+
+def _packed_rows(n: int, g: int, i):
+    """Row ``i`` of packed group ``g`` in a lane-tile-major scratch (lane
+    tile j at rows ``j*n .. j*n + n - 1``): one sublane-strided (8, 128)
+    window whose sublane k is row i of lane tile ``8*g + k``."""
+    return pl.ds(g * SUBLANE * n + i, SUBLANE, stride=n)
+
+
+def _packed_pass(coeff_at, tiles_ref, init, *, pspec: PassSpec, order: int,
+                 n: int, groups: int, reverse: bool, unroll: int):
+    """One sweep pass, in place, over a lane-tile-major scratch holding
+    ``groups`` packed groups of 8 lane tiles.  Each trip reads row i's
+    coefficients once and steps every group: a whole (8, 128) vreg of
+    1024 systems per load, op and store, with ``_sweep_step``'s arithmetic.
+    ``init`` holds one carry tuple per group."""
+    sources = [src for src, _ in pspec.terms]
+    if pspec.scale is not None:
+        sources.append(pspec.scale)
+
+    def body(t, carries):
+        i = n - 1 - t if reverse else t
+        coeffs = {src: coeff_at(src, i) for src in sources}
+        out = []
+        for g, carry in enumerate(carries):
+            rows = _packed_rows(n, g, i)
+            acc = _sweep_step(tiles_ref[rows, :], carry, pspec,
+                              coeffs.__getitem__)
+            tiles_ref[rows, :] = acc
+            out.append((acc,) + carry[:order - 1])
+        return tuple(out)
+
+    return jax.lax.fori_loop(0, n, body, init, unroll=unroll)
 
 
 def _factor_pass(diag_at, rhs_ref, coef_store, out_ref, init, *, order: int,
@@ -744,14 +809,15 @@ def _compute_dtype(dtype):
 
 
 def _compiler_params(interpret: bool, *, streamed: bool,
-                     prefetch: bool = False) -> dict:
+                     prefetch: bool = False,
+                     limit: int = VMEM_LIMIT_BYTES) -> dict:
     """Mosaic knobs for a compiled kernel: the scoped-VMEM limit the fit
-    checks budget against (``common.VMEM_LIMIT_BYTES``) and the grid's
-    dimension semantics.  Resident grids walk independent lane tiles
-    (``parallel``).  On the streamed/fused 2-D grids the N-chunk axis is
-    ``arbitrary`` (its carry scratch is sequential); ``prefetch=True``
-    marks the lane axis ``parallel`` too.  Interpret mode (CPU CI) takes
-    no compiler params at all."""
+    checks budget against (``limit``: ``common.vmem_limit`` of the spec)
+    and the grid's dimension semantics.  Resident grids walk independent
+    lane tiles (``parallel``).  On the streamed/fused 2-D grids the
+    N-chunk axis is ``arbitrary`` (its carry scratch is sequential);
+    ``prefetch=True`` marks the lane axis ``parallel`` too.  Interpret
+    mode (CPU CI) takes no compiler params at all."""
     if interpret:
         return {}
     if not streamed:
@@ -759,7 +825,7 @@ def _compiler_params(interpret: bool, *, streamed: bool,
     else:
         sem = ("parallel" if prefetch else "arbitrary", "arbitrary")
     return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=sem, vmem_limit_bytes=VMEM_LIMIT_BYTES)}
+        dimension_semantics=sem, vmem_limit_bytes=limit)}
 
 
 def _resolve_interpret(interpret):
@@ -812,6 +878,35 @@ def _shared_resident_kernel(*refs, spec: SweepSpec, n: int, unroll: int):
                 length=n, reverse=False, unroll=unroll)
     _solve_pass(at, x_ref, x_ref, zeros, pspec=bwd, order=spec.order,
                 length=n, reverse=True, unroll=unroll)
+
+
+def _shared_packed_kernel(*refs, spec: SweepSpec, n: int, unroll: int):
+    """``_shared_resident_kernel`` with 8 lane tiles on the sublanes.  The
+    block is copied into a lane-tile-major scratch (lane tile j at rows
+    ``j*n ..``), both passes sweep it in place a whole vreg at a time
+    (``_packed_pass``), and it is copied back out.  Every element takes the
+    row sweep's arithmetic in the same order, so the two are bit-equal."""
+    if spec.uniform:
+        eps_ref, lhs_ref, in_ref, x_ref, tiles_ref = refs
+    else:
+        (lhs_ref, in_ref, x_ref, tiles_ref), eps_ref = refs, None
+    fwd, bwd = spec.passes()
+    at = _shared_coeff(lhs_ref, eps_ref)
+    n_tiles = in_ref.shape[1] // LANE
+    # the zero seed is loaded, not a constant, for the reason
+    # ``_zero_carries`` gives; the copy below overwrites its vreg
+    tiles_ref[pl.ds(0, SUBLANE), :] = jnp.zeros((SUBLANE, LANE),
+                                                tiles_ref.dtype)
+    zero = tiles_ref[pl.ds(0, SUBLANE), :]
+    zeros = ((zero,) * spec.order,) * (n_tiles // SUBLANE)
+    for j in range(n_tiles):
+        tiles_ref[pl.ds(j * n, n), :] = in_ref[:, pl.ds(j * LANE, LANE)]
+    kw = dict(order=spec.order, n=n, groups=n_tiles // SUBLANE,
+              unroll=unroll)
+    _packed_pass(at, tiles_ref, zeros, pspec=fwd, reverse=False, **kw)
+    _packed_pass(at, tiles_ref, zeros, pspec=bwd, reverse=True, **kw)
+    for j in range(n_tiles):
+        x_ref[:, pl.ds(j * LANE, LANE)] = tiles_ref[pl.ds(j * n, n), :]
 
 
 def _shared_streamed_kernel(*refs, pspec: PassSpec, order: int, block_n: int,
@@ -884,8 +979,9 @@ def shared_solver(spec: SweepSpec):
     the (1, 1) uniform parameter operand.  ``rhs`` may arrive at a packed
     storage dtype (bf16); the result is at the fp32-promoted compute
     dtype.  Callers pad: M % block_m == 0, and for streamed specs
-    N % block_n == 0.  ``interpret=None`` compiles on a TPU and interprets
-    elsewhere."""
+    N % block_n == 0.  Packed specs take the RHS at the compute dtype,
+    N % 8 == 0 and block_m % 1024 == 0 (``ops`` engages them only then).
+    ``interpret=None`` compiles on a TPU and interprets elsewhere."""
     assert spec.layout == "shared"
 
     if not spec.streamed:
@@ -901,17 +997,30 @@ def shared_solver(spec: SweepSpec):
             if spec.uniform:
                 in_specs.insert(0, param_spec())
                 args.insert(0, eps)
+            if spec.packed:
+                if (rhs.dtype != cdt or n % SUBLANE
+                        or block_m % (SUBLANE * LANE)):
+                    raise ValueError(
+                        f"{spec.name} needs the RHS at the compute dtype, "
+                        f"N % {SUBLANE} == 0 and block_m % "
+                        f"{SUBLANE * LANE} == 0; got {rhs.dtype}, N={n}, "
+                        f"block_m={block_m}")
+                kernel = _shared_packed_kernel
+                scratch = pltpu.VMEM((block_m // LANE * n, LANE), cdt)
+            else:
+                kernel = _shared_resident_kernel
+                scratch = pltpu.VMEM((spec.order, block_m), cdt)
             return pl.pallas_call(
-                functools.partial(_shared_resident_kernel, spec=spec, n=n,
-                                  unroll=unroll),
+                functools.partial(kernel, spec=spec, n=n, unroll=unroll),
                 grid=(m // block_m,),
                 in_specs=in_specs,
                 out_specs=_col_spec(n, block_m),
                 out_shape=_out_struct((n, m), cdt, *args),
-                scratch_shapes=[pltpu.VMEM((spec.order, block_m), cdt)],
+                scratch_shapes=[scratch],
                 interpret=interpret,
                 name=spec.name,
-                **_compiler_params(interpret, streamed=False),
+                **_compiler_params(interpret, streamed=False,
+                                   limit=vmem_limit(spec)),
             )(*args)
         return solver
 

@@ -31,10 +31,10 @@ from repro import scopes
 from repro.core import (PentaFactor, PeriodicPentaFactor,
                         PeriodicTridiagFactor, TridiagFactor)
 from . import common as _common
-from .common import (LANE, canonical_storage_dtype, check_fits,
-                     default_interpret, pad_lanes, pad_sweep,
-                     smem_working_set, vmem_working_set)
-from .engine import (RecurrenceSpec, SweepSpec, batch_solver,
+from .common import (LANE, PACK, SUBLANE, canonical_storage_dtype,
+                     check_fits, default_interpret, pad_lanes, pad_sweep,
+                     smem_working_set, spec_fits, vmem_working_set)
+from .engine import (REGISTRY, RecurrenceSpec, SweepSpec, batch_solver,
                      find_recurrence_spec, find_spec, recurrence_solver,
                      shared_solver)
 from . import fused_cn, fused_cn_penta
@@ -85,6 +85,46 @@ def _check_spec_vmem(spec: SweepSpec, n: int, block_m: int,
     blocks stored at a packed storage dtype."""
     check_fits(spec, n, block_m, block_n,
                itemsize=jnp.promote_types(dtype, jnp.float32).itemsize)
+
+
+#: Lane tiles of the packed sweep, widest first: ``block_m / PACK`` whole
+#: vregs are swept per trip.
+_PACKED_BLOCK_M = (4 * PACK, 2 * PACK, PACK)
+
+
+def _packed_choice(spec: SweepSpec, n: int, m: int, dtype, sdt):
+    """``(packed spec, block_m)`` when the packed sweep serves a resident
+    shared ``spec`` on an (n, m) RHS, else None.  It does when the shape
+    allows whole packed groups and nothing else: M a multiple of 1024
+    (8 lane tiles, so no lane padding), N % 8 == 0 (the lane-tile-major
+    copy stays tile-aligned), and the RHS stored at the compute dtype.
+    Its lane tile is the widest that divides M and fits the spec-derived
+    working set."""
+    cdt = jnp.promote_types(dtype, jnp.float32)
+    if (spec.streamed or n % SUBLANE or m % PACK or dtype != cdt
+            or (sdt is not None and sdt != cdt)):
+        return None
+    packed = REGISTRY[spec.packed_name]
+    for block_m in _PACKED_BLOCK_M:
+        if m % block_m == 0 and spec_fits(packed, n, block_m,
+                                          itemsize=cdt.itemsize):
+            return packed, block_m
+    return None
+
+
+def _shared_spec(bandwidth: int, mode: str, n: int, m: int, dtype, sdt, *,
+                 block_m: int, block_n: int | None, transposed: bool,
+                 fused: bool) -> tuple:
+    """``(spec, block_m)`` of the shared-LHS kernel that serves this solve:
+    the packed sweep where ``_packed_choice`` allows it, else the row
+    sweep (resident or streamed) at the caller's blocks, fit-checked."""
+    spec = find_spec(bandwidth, mode, streamed=block_n is not None,
+                     transposed=transposed, fused=fused)
+    choice = _packed_choice(spec, n, m, dtype, sdt)
+    if choice is not None:
+        return choice
+    _check_spec_vmem(spec, n, block_m, block_n, dtype)
+    return spec, block_m
 
 
 def _pad_cast(operands, block_m: int, block_n: int | None, sdt,
@@ -152,14 +192,17 @@ def thomas_constant(f: TridiagFactor, d: jax.Array, *, block_m: int = 128,
     RHS stream at bf16 in HBM (fp32 factor and accumulation in-kernel;
     the solve returns fp32).  ``prefetch=True`` marks the lane-tile grid
     axis parallel on hardware (no-op under interpret).  ``transposed=True`` solves
-    A^T x = d from the SAME stored factor (the adjoint sweeps)."""
+    A^T x = d from the SAME stored factor (the adjoint sweeps).  A resident
+    solve whose shape allows it runs the packed sweep (8 lane tiles per
+    vreg; ``_packed_choice``), bit-equal to the row sweep, at the lane tile
+    the packed fit picks instead of ``block_m``."""
     if interpret is None:
         interpret = default_interpret()
-    n = d.shape[0]
+    n, m = d.shape
     sdt = canonical_storage_dtype(storage_dtype)
-    spec = find_spec(3, "constant", streamed=block_n is not None,
-                     transposed=transposed, fused=fused)
-    _check_spec_vmem(spec, n, block_m, block_n, d.dtype)
+    spec, block_m = _shared_spec(3, "constant", n, m, d.dtype, sdt,
+                                 block_m=block_m, block_n=block_n,
+                                 transposed=transposed, fused=fused)
     with jax.named_scope(scopes.kernel(spec.name)):
         lhs = stack_tridiag_lhs(f, transposed=transposed)
         return _shared_dispatch(spec, lhs, d, sdt, block_m=block_m,
@@ -221,15 +264,16 @@ def penta_constant(f: PentaFactor, rhs: jax.Array, *, block_m: int = 128,
     ascend/descend kernel — half the streamed traffic);
     ``storage_dtype="bf16"`` streams the RHS at bf16 (fp32 factor and
     carries in-kernel); ``transposed=True`` solves A^T x = rhs from the SAME
-    stored factor."""
+    stored factor.  Resident solves take the packed sweep where the shape
+    allows it, as in ``thomas_constant``."""
     if interpret is None:
         interpret = default_interpret()
-    n = rhs.shape[0]
+    n, m = rhs.shape
     sdt = canonical_storage_dtype(storage_dtype)
-    spec = find_spec(5, "uniform" if uniform else "constant",
-                     streamed=block_n is not None, transposed=transposed,
-                     fused=fused)
-    _check_spec_vmem(spec, n, block_m, block_n, rhs.dtype)
+    spec, block_m = _shared_spec(5, "uniform" if uniform else "constant",
+                                 n, m, rhs.dtype, sdt, block_m=block_m,
+                                 block_n=block_n, transposed=transposed,
+                                 fused=fused)
     with jax.named_scope(scopes.kernel(spec.name)):
         eps = _uniform_eps_param(f, rhs.dtype) if uniform else None
         lhs = stack_penta_lhs(f, uniform=uniform, transposed=transposed)

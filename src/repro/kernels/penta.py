@@ -50,5 +50,6 @@ def hbm_traffic_bytes(n: int, m: int, dtype=jnp.float32) -> dict:
     ``uniform`` (2NM + 4N + 1), ``batch`` (7NM), the ``_streamed``
     variants (two passes: one extra intermediate round trip, re-streamed
     LHS; batch additionally round-trips the spilled gamma/delta = 13NM),
-    and the ``_t`` transposed twins of the shared variants."""
+    the ``_t`` transposed twins of the shared variants, and the
+    ``_packed`` variants (the same streams as their row siblings)."""
     return traffic_table(5, n, m, dtype)

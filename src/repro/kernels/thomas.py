@@ -47,6 +47,8 @@ def hbm_traffic_bytes(n: int, m: int, dtype=jnp.float32) -> dict:
     (4NM + 6N — the intermediate d_hat makes one extra HBM round trip and
     both passes re-stream the shared LHS), ``batch_streamed`` (9NM — the
     spilled c_hat and the intermediate each round-trip), plus the ``_t``
-    transposed twins of the shared variants (same streams, shifted rows).
+    transposed twins of the shared variants (same streams, shifted rows)
+    and ``constant_packed`` (the same streams as ``constant``: the packed
+    sweep relayouts in VMEM).
     """
     return traffic_table(3, n, m, dtype)

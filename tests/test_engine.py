@@ -26,22 +26,30 @@ N, M = 45, 70
 BLOCK_M, BLOCK_N = 64, 16
 
 
-def _tridiag_factor(rng):
-    a = rng.uniform(-1, 1, N).astype(np.float32)
-    c = rng.uniform(-1, 1, N).astype(np.float32)
+# the packed specs engage only on whole packed groups (N % 8, M % 1024)
+PACKED_N, PACKED_M = 48, 2048
+
+
+def _shape(spec) -> tuple:
+    return (PACKED_N, PACKED_M) if getattr(spec, "packed", False) else (N, M)
+
+
+def _tridiag_factor(rng, n=N):
+    a = rng.uniform(-1, 1, n).astype(np.float32)
+    c = rng.uniform(-1, 1, n).astype(np.float32)
     b = (np.abs(a) + np.abs(c) + 2.5).astype(np.float32)
     return thomas_factor(*map(jnp.asarray, (a, b, c)))
 
 
-def _penta_coeffs(rng, uniform):
+def _penta_coeffs(rng, uniform, n=N):
     if uniform:
-        one = np.ones(N, np.float32)
+        one = np.ones(n, np.float32)
         s = 0.11
         return s * one, -4 * s * one, (1 + 6 * s) * one, -4 * s * one, s * one
-    a = rng.uniform(-1, 1, N).astype(np.float32)
-    b = rng.uniform(-1, 1, N).astype(np.float32)
-    d = rng.uniform(-1, 1, N).astype(np.float32)
-    e = rng.uniform(-1, 1, N).astype(np.float32)
+    a = rng.uniform(-1, 1, n).astype(np.float32)
+    b = rng.uniform(-1, 1, n).astype(np.float32)
+    d = rng.uniform(-1, 1, n).astype(np.float32)
+    e = rng.uniform(-1, 1, n).astype(np.float32)
     c = (np.abs(a) + np.abs(b) + np.abs(d) + np.abs(e) + 4.0).astype(
         np.float32)
     return a, b, c, d, e
@@ -108,13 +116,14 @@ def _run_spec(spec, rhs):
                   else penta_factor_solve)
         return got, oracle(*diags, rhs)
     if spec.bandwidth == 3:
-        f = _tridiag_factor(rng)
+        f = _tridiag_factor(rng, rhs.shape[0])
         got = kops.thomas_constant(f, rhs, block_m=BLOCK_M, block_n=block_n,
                                    fused=getattr(spec, "fused", False),
                                    interpret=True, transposed=spec.transposed)
         want = (thomas_solve_t if spec.transposed else thomas_solve)(f, rhs)
         return got, want
-    f = penta_factor(*map(jnp.asarray, _penta_coeffs(rng, spec.uniform)))
+    f = penta_factor(*map(jnp.asarray, _penta_coeffs(rng, spec.uniform,
+                                                     rhs.shape[0])))
     got = kops.penta_constant(f, rhs, block_m=BLOCK_M, block_n=block_n,
                               fused=getattr(spec, "fused", False),
                               interpret=True, uniform=spec.uniform,
@@ -128,11 +137,11 @@ def _run_spec(spec, rhs):
 # ---------------------------------------------------------------------------
 
 def test_registry_covers_the_variant_matrix():
-    """2 bandwidths x (shared: fwd/transposed x resident/streamed/fused
-    [x uniform for penta]) + (batch: resident/streamed/fused) = 24 sweep
-    specs, plus the gated recurrence family (2 orders x fwd/rev x
-    resident/streamed) = 32 specs total."""
-    assert len(REGISTRY) == 32
+    """2 bandwidths x (shared: fwd/transposed x resident/packed/streamed/
+    fused [x uniform for penta]) + (batch: resident/streamed/fused) = 30
+    sweep specs, plus the gated recurrence family (2 orders x fwd/rev x
+    resident/streamed) = 38 specs total."""
+    assert len(REGISTRY) == 38
     for order in (1, 2):
         for reverse in (False, True):
             for streamed in (False, True):
@@ -149,6 +158,10 @@ def test_registry_covers_the_variant_matrix():
                     assert SweepSpec(bw, "shared", transposed=transposed,
                                      streamed=streamed, fused=fused,
                                      uniform=True).name in REGISTRY
+            for uniform in ((False, True) if bw == 5 else (False,)):
+                assert SweepSpec(bw, "shared", transposed=transposed,
+                                 uniform=uniform,
+                                 packed=True).name in REGISTRY
         for streamed, fused in ((False, False), (True, False), (True, True)):
             assert SweepSpec(bw, "batch", streamed=streamed,
                              fused=fused).name in REGISTRY
@@ -177,9 +190,9 @@ def test_find_spec_maps_tridiag_uniform_to_constant():
 def test_spec_parity_matrix(name):
     spec = REGISTRY[name]
     rng = np.random.default_rng(7)
-    rhs = jnp.asarray(rng.normal(size=(N, M)).astype(np.float32))
+    rhs = jnp.asarray(rng.normal(size=_shape(spec)).astype(np.float32))
     got, want = _run_spec(spec, rhs)
-    assert got.shape == (N, M)
+    assert got.shape == _shape(spec)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=5e-5, atol=5e-5)
 

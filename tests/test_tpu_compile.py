@@ -9,6 +9,7 @@ check that the compiled program holds the Pallas kernel.  Nothing runs.
 """
 
 import os
+import re
 
 import pytest
 
@@ -16,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import engine
+from repro.kernels import common, engine, ops
 from repro.kernels.fused_cn import fused_cn_tridiag_pallas
 from repro.kernels.fused_cn_penta import fused_cn_penta_pallas
 from repro.core import recurrence
@@ -31,6 +32,8 @@ LARGE_N, LARGE_M = 16384, 8192
 STENCIL_N, STENCIL_M = 1024, 4096
 #: mamba2-130m's SSD inter-chunk scan at batch 8 x seq 2048.
 SSD_N, SSD_M = 2048 // 64, 8 * 24 * 64 * 128
+#: The benchmark's CN cells: N=1024, M=524288.
+CN_N, CN_M = 1024, 524288
 
 
 @pytest.fixture(scope="module")
@@ -132,6 +135,38 @@ def _compile_spec(compile_for_chip, name, n, m, *, storage=jnp.float32,
 def test_sweep_kernel_compiles_for_v5e(compile_for_chip, name, n, m, fused):
     calls, want = _compile_spec(compile_for_chip, name, n, m, fused=fused)
     assert calls == want
+
+
+def _scoped_vmem(text: str, key: str) -> int:
+    """Bytes of the Pallas kernel's ``key`` scoped-memory config in the
+    compiled HLO: ``scoped_memory_configs`` is the limit it requested,
+    ``used_scoped_memory_configs`` what Mosaic allocated."""
+    (call,) = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    (size,) = re.findall(rf'"{key}":\[\{{[^}}]*"size":"(\d+)"', call)
+    return int(size)
+
+
+@pytest.mark.parametrize("row", ["thomas_constant", "penta_constant"])
+def test_packed_sweep_compiles_for_v5e_within_its_vmem_limit(
+        one_chip, no_persistent_cache, row):
+    """The CN cells' solve dispatches the packed sweep; compiled for a v5e
+    it allocates no more VMEM than its fit model counts, and no more than
+    the limit it requests."""
+    spec, block_m = ops._packed_choice(engine.REGISTRY[row], CN_N, CN_M,
+                                       jnp.float32, None)
+    assert spec.name == f"{row}_packed"
+    solver = engine.shared_solver(spec)
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in ((spec.lhs_rows, CN_N), (CN_N, CN_M))]
+    text = jax.jit(lambda lhs, rhs: solver(
+        lhs, rhs, block_m=block_m, interpret=False)).lower(
+            *args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    used = _scoped_vmem(text, "used_scoped_memory_configs")
+    limit = _scoped_vmem(text, "scoped_memory_configs")
+    assert limit == common.vmem_limit(spec)
+    assert used <= common.spec_working_set(spec, CN_N, block_m)[0] <= limit
 
 
 @pytest.mark.parametrize("name,n,m,fused", [
